@@ -76,6 +76,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke:
         args.docs, args.culled, args.orders = 400, 200, [8]
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     t_all = time.perf_counter()
 

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ref import EXACT
 from repro.sparse.csr import Csr, csr_from_dense, csr_row_gather_dense
 from repro.sparse.ell import Ell, ell_from_csr
 
@@ -124,15 +125,15 @@ class DenseBackend:
     def row_sq(self, rows: jax.Array) -> jax.Array:
         """‖x‖² per row — f32[B] (the constant term of squared distances)."""
         xb = self.x[rows].astype(jnp.float32)
-        return jnp.einsum("bd,bd->b", xb, xb)
+        return jnp.einsum("bd,bd->b", xb, xb, precision=EXACT)
 
     def cross_nodes(self, rows: jax.Array, centers: jax.Array) -> jax.Array:
         """x_b · c_bm for per-query gathered node centres ``centers`` [B, m1, d]."""
-        return jnp.einsum("bd,bmd->bm", self.x[rows], centers)
+        return jnp.einsum("bd,bmd->bm", self.x[rows], centers, precision=EXACT)
 
     def cross_flat(self, rows: jax.Array, centers: jax.Array) -> jax.Array:
         """x_b · c_k against a flat centre set [K, d] → [B, K]."""
-        return self.x[rows] @ centers.T
+        return jnp.matmul(self.x[rows], centers.T, precision=EXACT)
 
     def nn_flat(
         self, rows: jax.Array, centers: jax.Array, valid: jax.Array
@@ -267,7 +268,7 @@ class EllSparseBackend:
         v = self.values[rows]                                  # [B, nnz]
         c = self.cols[rows]                                    # [B, nnz]
         gathered = jnp.take_along_axis(centers, c[:, None, :], axis=2)  # [B, m1, nnz]
-        return jnp.einsum("bn,bmn->bm", v, gathered)
+        return jnp.einsum("bn,bmn->bm", v, gathered, precision=EXACT)
 
     def cross_flat(self, rows: jax.Array, centers: jax.Array) -> jax.Array:
         """Flat centre set [K, d] → scores via the ``ell_spmm`` Pallas kernel
@@ -303,7 +304,7 @@ class EllSparseBackend:
         """‖x‖² − 2·S + ‖c‖², clamped, masked → f32[B, K] (shared by the nn/topk
         flat paths so their top-1 agree bit-for-bit)."""
         c32 = centers.astype(jnp.float32)
-        c_sq = jnp.einsum("kd,kd->k", c32, c32)
+        c_sq = jnp.einsum("kd,kd->k", c32, c32, precision=EXACT)
         dist = jnp.maximum(self.sq[rows][:, None] - 2.0 * scores + c_sq[None, :], 0.0)
         return jnp.where(valid[None, :], dist, jnp.inf)
 
@@ -409,7 +410,7 @@ class RandomProjection:
 
 @jax.jit
 def _apply_projection(matrix: jax.Array, x: jax.Array) -> jax.Array:
-    return x.astype(jnp.float32) @ matrix
+    return jnp.matmul(x.astype(jnp.float32), matrix, precision=EXACT)
 
 
 def make_projection(
@@ -672,8 +673,10 @@ class DenseDocShards(_DocShardsBase):
         queries ``xq`` [B, d] — shard_map-body view (same expressions as the
         single-device `_score_entries`, so distances agree)."""
         xd = self.x[ids].astype(jnp.float32)                   # [B, C, d]
-        c_sq = jnp.einsum("bcd,bcd->bc", xd, xd)
-        return c_sq - 2.0 * jnp.einsum("bd,bcd->bc", xq.astype(jnp.float32), xd)
+        c_sq = jnp.einsum("bcd,bcd->bc", xd, xd, precision=EXACT)
+        return c_sq - 2.0 * jnp.einsum(
+            "bd,bcd->bc", xq.astype(jnp.float32), xd, precision=EXACT
+        )
 
 
 @jax.tree_util.register_dataclass
@@ -705,7 +708,7 @@ class EllDocShards(_DocShardsBase):
         c = self.cols[ids]                                     # [B, C, nnz]
         b_idx = jnp.arange(xq.shape[0])[:, None, None]
         g = xq.astype(jnp.float32)[b_idx, c]                   # [B, C, nnz]
-        return self.sq[ids] - 2.0 * jnp.einsum("bcn,bcn->bc", v, g)
+        return self.sq[ids] - 2.0 * jnp.einsum("bcn,bcn->bc", v, g, precision=EXACT)
 
 
 DocShards = Union[DenseDocShards, EllDocShards]

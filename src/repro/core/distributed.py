@@ -18,23 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax>=0.6 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
-def shard_map(f, **kwargs):
-    """Version shim: jax<0.6 spells ``check_vma`` as ``check_rep``."""
-    try:
-        return _shard_map(f, **kwargs)
-    except TypeError:
-        if "check_vma" in kwargs:
-            kwargs = dict(kwargs)
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(f, **kwargs)
-
 from repro.core.kmeans import assign as _assign
+from repro.kernels.ref import EXACT
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -98,7 +83,7 @@ def distributed_lloyd_step(mesh: Mesh, use_kernel: bool = False):
     axes = data_axes(mesh)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=(P(None, None), P(axes), P()),
@@ -108,7 +93,7 @@ def distributed_lloyd_step(mesh: Mesh, use_kernel: bool = False):
         k = c.shape[0]
         idx, dist = _assign(xs, c, use_kernel=use_kernel)
         onehot = jax.nn.one_hot(idx, k, dtype=xs.dtype)
-        sums = jnp.einsum("nk,nd->kd", onehot, xs)
+        sums = jnp.einsum("nk,nd->kd", onehot, xs, precision=EXACT)
         counts = onehot.sum(axis=0)
         for ax in axes:  # hierarchical all-reduce: ICI first, then DCI
             sums = jax.lax.psum(sums, ax)
@@ -142,7 +127,9 @@ def distributed_kmeans(
 
     k1, k2 = jax.random.split(key)
     n_sample = min(x.shape[0], max(8 * k, 2048))
-    sample = x[jax.random.choice(k1, x.shape[0], (n_sample,), replace=False)]
+    picks = jax.random.choice(k1, x.shape[0], (n_sample,), replace=False)
+    # a gather from row-sharded x must name its output layout: replicated
+    sample = x.at[picks].get(out_sharding=NamedSharding(mesh, P(None, None)))
     centers = jax.device_put(
         kmeans_pp_init(k2, sample, k), NamedSharding(mesh, P(None, None))
     )
@@ -167,7 +154,7 @@ def distributed_assign_sharded_centers(mesh: Mesh, k_global: int, use_kernel: bo
     k_local = k_global // n_shards
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axes, None), P("model", None)),
         out_specs=(P(axes), P(axes)),

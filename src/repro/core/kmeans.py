@@ -20,6 +20,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import EXACT
+
 
 # ---------------------------------------------------------------------------
 # distances + assignment (the hot path — Pallas kernel behind a flag)
@@ -33,10 +35,10 @@ def pairwise_sqdist(
 ) -> jax.Array:
     """‖x−c‖² = ‖x‖² − 2·x·cᵀ + ‖c‖² — [B,K]. The matmul is the MXU hot spot."""
     if x_sq is None:
-        x_sq = jnp.einsum("nd,nd->n", x, x)
+        x_sq = jnp.einsum("nd,nd->n", x, x, precision=EXACT)
     if c_sq is None:
-        c_sq = jnp.einsum("kd,kd->k", centers, centers)
-    cross = x @ centers.T
+        c_sq = jnp.einsum("kd,kd->k", centers, centers, precision=EXACT)
+    cross = jnp.matmul(x, centers.T, precision=EXACT)
     return jnp.maximum(x_sq[:, None] - 2.0 * cross + c_sq[None, :], 0.0)
 
 
@@ -81,7 +83,7 @@ def _centroid_update(
     what the TPU path uses); ``segment`` = segment_sum scatter."""
     if via == "matmul":
         onehot = jax.nn.one_hot(idx, k, dtype=x.dtype) * w[:, None]   # [n,k]
-        sums = jnp.einsum("nk,nd->kd", onehot, x)
+        sums = jnp.einsum("nk,nd->kd", onehot, x, precision=EXACT)
         counts = onehot.sum(axis=0)
     else:
         sums = jax.ops.segment_sum(x * w[:, None], idx, num_segments=k)

@@ -45,6 +45,7 @@ from repro.core.autotune import resolve_knobs
 from repro.core.backend import VectorBackend, make_backend
 from repro.core.kmeans import kmeans
 from repro.core.profile import NULL_PROFILER
+from repro.kernels.ref import EXACT
 
 
 @jax.tree_util.register_dataclass
@@ -141,7 +142,7 @@ def _node_nearest_slot(
     centres: the backend supplies the cross term — MXU einsum for dense rows,
     an nnz-bounded column gather for sparse rows."""
     c = tree.centers[node_ids]                                   # [B, m1, d]
-    c_sq = jnp.einsum("bmd,bmd->bm", c, c)
+    c_sq = jnp.einsum("bmd,bmd->bm", c, c, precision=EXACT)
     cross = backend.cross_nodes(rows, c)
     dist = c_sq - 2.0 * cross
     valid = jnp.arange(tree.slots)[None, :] < tree.n_entries[node_ids][:, None]
@@ -221,7 +222,7 @@ def _nearest_in_leaf_backend(
     """(doc_id i32[B], sqdist f32[B]) — exact NN among the reached leaf's
     vectors, for any backend."""
     c = tree.centers[leaf_ids]                                   # [B, m1, d]
-    c_sq = jnp.einsum("bmd,bmd->bm", c, c)
+    c_sq = jnp.einsum("bmd,bmd->bm", c, c, precision=EXACT)
     diff_sq = c_sq - 2.0 * backend.cross_nodes(rows, c)
     valid = jnp.arange(tree.slots)[None, :] < tree.n_entries[leaf_ids][:, None]
     diff_sq = jnp.where(valid, diff_sq, jnp.inf)
@@ -251,7 +252,7 @@ def _group_rank(leaf_ids: jax.Array) -> jax.Array:
     return jnp.zeros((b,), jnp.int32).at[perm].set(rank_sorted)
 
 
-@functools.partial(jax.jit, static_argnames=("max_levels",))
+@functools.partial(jax.jit, static_argnames=("max_levels",), donate_argnums=0)
 def _insert_wave(
     tree: KTree,
     backend: VectorBackend,
@@ -268,7 +269,11 @@ def _insert_wave(
     accepted paths (dense mode), and appends accepted vectors to leaves —
     densifying *only this wave's rows* via the backend. Returns
     (tree, accepted bool[B]). Callers split overflowing nodes and loop until
-    nothing is pending (see :func:`build`)."""
+    nothing is pending (see :func:`build`).
+
+    The input tree is donated — its buffers become the output's, so a wave
+    never holds two trees (4.9 GB each at INEX width and 20k docs) — and
+    must not be used after the call; so are the split ops'."""
     m1 = tree.slots
     nmax = tree.max_nodes
     leaf_ids, path_nodes, path_slots = _route_descend(
@@ -292,16 +297,22 @@ def _insert_wave(
             upd = jnp.logical_and(accepted, jnp.asarray(l, jnp.int32) < levels)
             wa = upd.astype(x.dtype)
             n_l, s_l = path_nodes[l], path_slots[l]
+            # rows that update one (node, slot) entry form a group: each row
+            # gets its group's vector and weight sums from a [B, B] matmul,
+            # and only the touched entries are rewritten — no tree-sized
+            # temporary (every row of a group writes the same value)
+            group = jnp.logical_and(n_l[:, None] == n_l[None, :],
+                                    s_l[:, None] == s_l[None, :])
+            group = group.astype(x.dtype) * wa[None, :]
+            sum_x = jnp.matmul(group, x, precision=EXACT)         # [B, d]
+            old_counts = counts[n_l, s_l]
+            new_counts = old_counts + group.sum(axis=1)
+            new_centers = (
+                centers[n_l, s_l] * old_counts[:, None] + sum_x
+            ) / jnp.maximum(new_counts, 1e-12)[:, None]
             n_safe = jnp.where(upd, n_l, nmax)  # OOB rows are dropped
-            sum_x = jnp.zeros_like(centers).at[n_safe, s_l].add(x * wa[:, None])
-            cnt = jnp.zeros_like(counts).at[n_safe, s_l].add(wa)
-            new_counts = counts + cnt
-            centers = jnp.where(
-                (cnt > 0)[..., None],
-                (centers * counts[..., None] + sum_x) / jnp.maximum(new_counts, 1e-12)[..., None],
-                centers,
-            )
-            counts = new_counts
+            centers = centers.at[n_safe, s_l].set(new_centers)
+            counts = counts.at[n_safe, s_l].set(new_counts)
         tree = dataclasses.replace(tree, centers=centers, counts=counts)
 
     # ---- leaf append
@@ -406,7 +417,7 @@ def _split_parts(
     )
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def split_node(tree: KTree, node_id: jax.Array, key: jax.Array) -> KTree:
     """Split one overflowing node (n_entries == m+1) into two with 2-means and
     promote the two means (or exemplars, medoid mode) to the parent. The caller
@@ -479,7 +490,7 @@ def split_node(tree: KTree, node_id: jax.Array, key: jax.Array) -> KTree:
     )
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def split_nodes_batch(
     tree: KTree, node_ids: jax.Array, valid: jax.Array, keys: jax.Array
 ) -> KTree:
@@ -583,7 +594,8 @@ def _split_all_overflowing(tree: KTree, key: jax.Array) -> Tuple[KTree, jax.Arra
     would overflow a full parent are deferred one round by the batch op)."""
     while True:
         n_nodes = int(tree.n_nodes)
-        n_entries = np.asarray(tree.n_entries[:n_nodes])
+        # slice on the host: a device slice of a new length compiles anew
+        n_entries = np.asarray(tree.n_entries)[:n_nodes]
         over = np.nonzero(n_entries > tree.order)[0]
         if over.size == 0:
             return tree, key
@@ -593,7 +605,7 @@ def _split_all_overflowing(tree: KTree, key: jax.Array) -> Tuple[KTree, jax.Arra
             key, sub = jax.random.split(key)
             tree = split_node(tree, jnp.int32(root), sub)
             continue
-        heights = np.asarray(tree.height[:n_nodes])[over]
+        heights = np.asarray(tree.height)[over]
         batch = over[heights == heights.max()][:_SPLIT_BATCH_CAP]
         size = _split_batch_size(batch.size)
         ids = np.zeros(size, np.int32)
@@ -773,6 +785,9 @@ def insert(
     global ids (−1 = padding)."""
     if key is None:
         key = jax.random.PRNGKey(1)
+    # the waves and splits donate their tree: work on a copy, the caller's
+    # tree stays valid
+    tree = jax.tree_util.tree_map(jnp.copy, tree)
     be = make_backend(x)
     doc_ids = jnp.asarray(doc_ids, jnp.int32)
     rows = jnp.arange(be.n_docs, dtype=jnp.int32)

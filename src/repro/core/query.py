@@ -93,7 +93,7 @@ from repro.core.ktree import (
 )
 from repro.core.profile import NULL_PROFILER
 from repro.core.store import check_on_fault
-from repro.kernels.ref import topk_from_dist, topk_merge_ref
+from repro.kernels.ref import EXACT, topk_from_dist, topk_merge_ref
 
 
 def _score_entries(
@@ -109,7 +109,7 @@ def _score_entries(
     b, beam = frontier.shape
     m1 = tree.slots
     c = tree.centers[frontier].reshape(b, beam * m1, tree.dim)
-    c_sq = jnp.einsum("bmd,bmd->bm", c, c)
+    c_sq = jnp.einsum("bmd,bmd->bm", c, c, precision=EXACT)
     diff_sq = c_sq - 2.0 * backend.cross_nodes(rows, c)
     slot_ok = (
         jnp.arange(m1)[None, None, :] < tree.n_entries[frontier][:, :, None]
@@ -441,7 +441,7 @@ def _get_sharded_chunk_fn(mesh, shards_treedef, shards_specs, max_levels, beam, 
     local rows, score the owned ones against the local corpus block, take a
     local top-k, then all-gather the (k-wide) per-shard winners and merge with
     :func:`topk_merge_ref` — collective volume O(B·k·n_shards), never O(B·n)."""
-    from repro.core.distributed import data_axes, flat_shard_index, shard_map
+    from repro.core.distributed import data_axes, flat_shard_index
 
     key = (mesh, shards_treedef, shards_specs, max_levels, beam, k)
     fn = _SHARDED_FN_CACHE.get(key)
@@ -476,7 +476,7 @@ def _get_sharded_chunk_fn(mesh, shards_treedef, shards_specs, max_levels, beam, 
         g_i = g_i.reshape(n_shards, b, k).transpose(1, 0, 2)
         return topk_merge_ref(g_i, g_d, k)
 
-    smap = shard_map(
+    smap = jax.shard_map(
         leaf_merge,
         mesh=mesh,
         in_specs=(spec_tree, P(), P(), P(), P()),
@@ -544,7 +544,7 @@ def _get_store_merge_fn(mesh, kind: str, k: int):
     distances — and the all-gathered ``topk_merge_ref`` result — match the
     in-memory sharded path bit for bit); the collective stays
     O(B·k·n_shards)."""
-    from repro.core.distributed import data_axes, shard_map
+    from repro.core.distributed import data_axes
 
     key = (mesh, kind, k)
     fn = _STORE_MERGE_FN_CACHE.get(key)
@@ -560,9 +560,9 @@ def _get_store_merge_fn(mesh, kind: str, k: int):
         pool_idx, owned = pool_idx[0], owned[0]
         if kind == "dense":
             xd = pools[0][0][pool_idx].astype(jnp.float32)    # [B, C, d]
-            c_sq = jnp.einsum("bcd,bcd->bc", xd, xd)
+            c_sq = jnp.einsum("bcd,bcd->bc", xd, xd, precision=EXACT)
             part = c_sq - 2.0 * jnp.einsum(
-                "bd,bcd->bc", xq.astype(jnp.float32), xd
+                "bd,bcd->bc", xq.astype(jnp.float32), xd, precision=EXACT
             )
         else:
             pv, pc = pools[0][0], pools[1][0]                 # [U, nnz] each
@@ -571,7 +571,7 @@ def _get_store_merge_fn(mesh, kind: str, k: int):
             c = pc[pool_idx]
             b_idx = jnp.arange(xq.shape[0])[:, None, None]
             g = xq.astype(jnp.float32)[b_idx, c]
-            part = sq[pool_idx] - 2.0 * jnp.einsum("bcn,bcn->bc", v, g)
+            part = sq[pool_idx] - 2.0 * jnp.einsum("bcn,bcn->bc", v, g, precision=EXACT)
         part = jnp.where(jnp.logical_and(valid, owned), part, jnp.inf)
         pos, d_loc = topk_from_dist(part, k)
         ids_loc = jnp.where(
@@ -593,7 +593,7 @@ def _get_store_merge_fn(mesh, kind: str, k: int):
         )
         return ids, dist
 
-    smap = shard_map(
+    smap = jax.shard_map(
         merge,
         mesh=mesh,
         in_specs=(pool_spec, P(axes, None, None), P(axes, None, None),
@@ -992,8 +992,12 @@ def _rp_row_fetcher(src, in_dim: int):
         )
 
     def fetch(ids):
-        rows = be.take(jnp.asarray(ids, dtype=jnp.int32))
-        return np.asarray(rows).astype(np.float32, copy=False)
+        # pad to a power-of-two length (repeating the last id): every union
+        # size would otherwise compile its own gather
+        pad = _levels_bucket(ids.size) - ids.size
+        padded = np.concatenate([ids, np.repeat(ids[-1:], pad)])
+        rows = be.take(jnp.asarray(padded, dtype=jnp.int32))
+        return np.asarray(rows)[: ids.size].astype(np.float32, copy=False)
 
     return fetch
 

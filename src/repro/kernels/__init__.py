@@ -1,3 +1,21 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the K-tree hot spots (DESIGN.md §3.3/§3.4): fused
+nearest-centre search (``nn_assign``), its top-k generalisation
+(``nn_topk``), and sparse-document scoring (``ell_spmm``). ``ops`` pads and
+dispatches them; ``ref`` holds the pure-jnp oracles."""
+from __future__ import annotations
+
+_MIB = 1 << 20
+DEFAULT_SCOPED_VMEM = 16 * _MIB
+"""Mosaic's default scoped-VMEM limit per kernel on v5e (the core has
+128 MiB of VMEM; a kernel may ask for more than the default)."""
+
+
+def scoped_vmem_limit(pipelined_bytes: int, resident_bytes: int = 0) -> int:
+    """``vmem_limit_bytes`` for a kernel whose BlockSpec operands total
+    ``pipelined_bytes`` per grid step (the pipeline double-buffers each of
+    them) plus ``resident_bytes`` of scratch, with 8 MiB of headroom for
+    in-kernel temporaries; never below the default limit. The full-``D``
+    blocks at the culled vocabulary (d = 8000 → 8064) need ~17 MiB, just
+    over the default."""
+    need = 2 * pipelined_bytes + resident_bytes + 8 * _MIB
+    return max(DEFAULT_SCOPED_VMEM, -(-need // _MIB) * _MIB)

@@ -19,8 +19,14 @@ concatenates before the current tile, and argmin takes the first occurrence).
 Masked / padded centres carry +inf distance; exhausted buffer slots report
 centre id −1.
 
-VMEM per step (bm=bk=128, kq≤64, D≤8192 fp32): x 4 MiB + c 4 MiB + merge
-buffers ~0.4 MiB < 16 MiB.
+VMEM per step (bm=bk=128, D=8064 fp32): x 4.1 MiB + c 4.1 MiB, each
+double-buffered by the pipeline → 16.5 MiB, plus the unrolled merge's live
+[bm, kq+bk] candidate copies (~2·kq of them: 0.7 MiB at kq=4, 11 MiB at
+kq=64). That is over Mosaic's 16 MiB default scoped limit — the compiler
+refused kq ≥ 10 at 17.56 MiB — so the call sets ``vmem_limit_bytes`` from
+these sizes (``kernels.scoped_vmem_limit``: 25 MiB at kq=4, 36 MiB at
+kq=64). The bias is a 2-D ``[1, K]`` row: Mosaic refuses a 1-D
+block whose tiling differs from XLA's.
 """
 from __future__ import annotations
 
@@ -29,6 +35,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import scoped_vmem_limit
+from repro.kernels.ref import EXACT
 
 
 def _nn_topk_kernel(
@@ -38,15 +48,16 @@ def _nn_topk_kernel(
     x = x_ref[...]
     c = c_ref[...]
     cross = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=EXACT,
     )                                                        # [bm, bk]
     x32 = x.astype(jnp.float32)
     c32 = c.astype(jnp.float32)
-    x_sq = jnp.sum(x32 * x32, axis=1)                        # [bm]
+    x_sq = jnp.sum(x32 * x32, axis=1, keepdims=True)         # [bm, 1]
     c_sq = jnp.sum(c32 * c32, axis=1)                        # [bk]
-    dist = jnp.maximum(x_sq[:, None] - 2.0 * cross + c_sq[None, :], 0.0)
+    dist = jnp.maximum(x_sq - 2.0 * cross + c_sq[None, :], 0.0)
     # masked AND padded centres both carry +inf bias (built in ops.nn_topk)
-    dist = dist + bias_ref[...][None, :]
+    dist = dist + bias_ref[...]
     col = k * bk + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
 
     @pl.when(k == 0)
@@ -87,20 +98,25 @@ def nn_topk_pallas(
     interpret: bool = False,
 ):
     """Padded entry point — callers use repro.kernels.ops.nn_topk, which pads
-    B/K/D and builds the centre-mask bias. x: [B,D], centers: [K,D], bias: [K].
+    B/K/D and builds the centre-mask bias. x: [B,D], centers: [K,D],
+    bias: f32[1,K].
     Returns (dist f32[B,kq] ascending, idx i32[B,kq]; −1 id on padding)."""
     b, d = x.shape
     k, _ = centers.shape
     assert b % bm == 0 and k % bk == 0, "pad B and K first"
     grid = (b // bm, k // bk)
     kernel = functools.partial(_nn_topk_kernel, bk=bk, kq=kq)
+    block_bytes = (bm * d * x.dtype.itemsize + bk * d * centers.dtype.itemsize
+                   + 4 * bk + 8 * bm * kq)
+    # the unrolled merge keeps ~2·kq live [bm, kq+bk] candidate copies
+    merge_bytes = 2 * kq * bm * (kq + bk) * 4
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bm, kq), lambda i, j: (i, 0)),
@@ -110,5 +126,8 @@ def nn_topk_pallas(
             jax.ShapeDtypeStruct((b, kq), jnp.float32),
             jax.ShapeDtypeStruct((b, kq), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=scoped_vmem_limit(block_bytes, merge_bytes)
+        ),
         interpret=interpret,
     )(x, centers, bias)

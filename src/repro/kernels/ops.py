@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro.kernels.nn_assign import nn_assign_pallas
 from repro.kernels.nn_topk import nn_topk_pallas
 from repro.kernels.ell_spmm import ell_spmm_pallas
+from repro.kernels.ref import EXACT
 
 
 def _interpret() -> bool:
@@ -20,6 +21,15 @@ def _interpret() -> bool:
 
 def _pad_to(n: int, mult: int) -> int:
     return -(-n // mult) * mult
+
+
+def _centre_bias(k: int, kp: int, valid: Optional[jax.Array]) -> jax.Array:
+    """f32[1, kp] additive centre mask: 0 on valid centres, +inf on masked
+    ones and on the padded rows kp − k (which must never win)."""
+    bias = jnp.zeros((k,), jnp.float32)
+    if valid is not None:
+        bias = jnp.where(valid, 0.0, jnp.inf)
+    return jnp.pad(bias, (0, kp - k), constant_values=jnp.inf)[None, :]
 
 
 def nn_assign(
@@ -38,13 +48,9 @@ def nn_assign(
     bp, kp, dp = _pad_to(b, bm), _pad_to(k, bk), _pad_to(d, 128)
     xq = jnp.pad(x, ((0, bp - b), (0, dp - d)))
     cq = jnp.pad(centers, ((0, kp - k), (0, dp - d)))
-    bias = jnp.zeros((k,), jnp.float32)
-    if valid is not None:
-        bias = jnp.where(valid, 0.0, jnp.inf)
-    # padded centre rows must never win: +inf bias
-    bias = jnp.pad(bias, (0, kp - k), constant_values=jnp.inf)
+    bias = _centre_bias(k, kp, valid)
     dist, idx = nn_assign_pallas(xq, cq, bias, bm=bm, bk=bk, interpret=_interpret())
-    return idx[:b], dist[:b]
+    return idx[:b, 0], dist[:b, 0]
 
 
 def nn_topk(
@@ -64,11 +70,7 @@ def nn_topk(
     bp, kp, dp = _pad_to(b, bm), _pad_to(kc, bk), _pad_to(d, 128)
     xq = jnp.pad(x, ((0, bp - b), (0, dp - d)))
     cq = jnp.pad(centers, ((0, kp - kc), (0, dp - d)))
-    bias = jnp.zeros((kc,), jnp.float32)
-    if valid is not None:
-        bias = jnp.where(valid, 0.0, jnp.inf)
-    # padded centre rows must never win: +inf bias
-    bias = jnp.pad(bias, (0, kp - kc), constant_values=jnp.inf)
+    bias = _centre_bias(kc, kp, valid)
     dist, idx = nn_topk_pallas(
         xq, cq, bias, kq=k, bm=bm, bk=bk, interpret=_interpret()
     )
@@ -86,11 +88,13 @@ def ell_spmm(
     b, nnz = values.shape
     k, d = centers.shape
     bp, kp, dp = _pad_to(b, bm), _pad_to(k, bk), _pad_to(d, 128)
-    vq = jnp.pad(values, ((0, bp - b), (0, 0)))
-    cq = jnp.pad(cols, ((0, bp - b), (0, 0)))
+    # slot-major (transposed) ELL, so the kernel reads one nnz slot of a
+    # row tile as a sublane row; padded rows are all-padding (value 0)
+    vt = jnp.pad(values, ((0, bp - b), (0, 0))).T
+    ct = jnp.pad(cols, ((0, bp - b), (0, 0))).T
     ctq = jnp.pad(centers, ((0, kp - k), (0, dp - d)))
-    s = ell_spmm_pallas(vq, cq, ctq, bm=bm, bk=bk, interpret=_interpret())
-    return s[:b, :k]
+    s_t = ell_spmm_pallas(vt, ct, ctq, bm=bm, bk=bk, interpret=_interpret())
+    return s_t[:k, :b].T
 
 
 def medoid_assign_sparse(
@@ -104,7 +108,7 @@ def medoid_assign_sparse(
     the medoid K-tree scoring path: ‖x‖² − 2·S + ‖c‖² with S from ell_spmm."""
     s = ell_spmm(values, cols, centers)
     c32 = centers.astype(jnp.float32)
-    c_sq = jnp.einsum("kd,kd->k", c32, c32)
+    c_sq = jnp.einsum("kd,kd->k", c32, c32, precision=EXACT)
     dist = jnp.maximum(row_sq[:, None] - 2.0 * s + c_sq[None, :], 0.0)
     if valid is not None:
         dist = jnp.where(valid[None, :], dist, jnp.inf)

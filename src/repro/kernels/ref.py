@@ -7,6 +7,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+EXACT = jax.lax.Precision.HIGHEST
+"""Matmul precision of every distance computation in the repo. On a TPU the
+default f32 matmul is one bf16 pass (~3 significant digits), which would
+make returned distances, centre means and routing differ from the f32
+semantics the paper and the reference implementations define; ``HIGHEST``
+keeps f32 accuracy on the MXU. CPU backends compute f32 either way."""
+
 
 def _full_sqdist(
     x: jax.Array, centers: jax.Array, valid: Optional[jax.Array] = None
@@ -17,9 +24,9 @@ def _full_sqdist(
     x32 = x.astype(jnp.float32)
     c32 = centers.astype(jnp.float32)
     d = (
-        jnp.einsum("bd,bd->b", x32, x32)[:, None]
-        - 2.0 * x32 @ c32.T
-        + jnp.einsum("kd,kd->k", c32, c32)[None, :]
+        jnp.einsum("bd,bd->b", x32, x32, precision=EXACT)[:, None]
+        - 2.0 * jnp.matmul(x32, c32.T, precision=EXACT)
+        + jnp.einsum("kd,kd->k", c32, c32, precision=EXACT)[None, :]
     )
     d = jnp.maximum(d, 0.0)
     if valid is not None:
@@ -83,4 +90,4 @@ def ell_spmm_ref(values: jax.Array, cols: jax.Array, centers: jax.Array) -> jax.
     x_dense = jnp.zeros((b, d), jnp.float32)
     rows = jnp.broadcast_to(jnp.arange(b)[:, None], cols.shape)
     x_dense = x_dense.at[rows, cols].add(values.astype(jnp.float32))
-    return x_dense @ centers.astype(jnp.float32).T
+    return jnp.matmul(x_dense, centers.astype(jnp.float32).T, precision=EXACT)

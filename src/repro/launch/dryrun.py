@@ -87,8 +87,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 def cost_summary(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns a per-program list
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import reduced_cfg
 
 
@@ -754,6 +755,7 @@ def main():
                     "The engine watchdog fails overdue requests with "
                     "EngineTimeout so no caller can hang (--engine)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fsck:
         from repro.core.fsck import fsck_store, repair_store
 

@@ -241,11 +241,9 @@ def flash_decode_attention(
 
 
 def shard_map_fn(f, mesh, in_specs, out_specs):
-    try:
-        sm = jax.shard_map
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def decode_attention(

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.sparse.csr import Csr
+from repro.kernels.ref import EXACT
 
 
 class Ell(NamedTuple):
@@ -66,7 +67,7 @@ def ell_dot_dense(e: Ell, dense_t: jax.Array) -> jax.Array:
     version. Memory: n_rows × nnz_max × K intermediate — callers tile rows.
     """
     gathered = jnp.take(dense_t, e.cols, axis=0)           # [n, nnz, K]
-    return jnp.einsum("nj,njk->nk", e.values, gathered)
+    return jnp.einsum("nj,njk->nk", e.values, gathered, precision=EXACT)
 
 
 def ell_row_norms(e: Ell) -> jax.Array:

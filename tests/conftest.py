@@ -2,9 +2,14 @@ import os
 import sys
 import types
 
+import pytest
+
 # src layout import without install; tests run single-device (the 512-device
 # override belongs ONLY to the dry-run entry point)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# tests never use the persistent compilation cache — neither in this process
+# nor in the entry points they start as subprocesses (repro.launch.compile_cache)
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 
 def pytest_configure(config):
@@ -13,6 +18,28 @@ def pytest_configure(config):
         "slow: multi-minute tests (subprocess re-runs under forced device "
         "counts); deselect with -m 'not slow'",
     )
+
+
+_MAPS = "/proc/self/maps"
+_MAPS_HIGH_WATER = 20_000
+
+
+@pytest.fixture(autouse=True)
+def _drop_compiled_programs():
+    """Release the compiled programs once a worker holds too many. XLA:CPU
+    maps code pages for every executable the jit caches keep alive (a tree
+    build compiles dozens); a worker that runs many tests reaches the
+    kernel's 65,530-mapping limit, and its next compile crashes the process.
+    Clearing at 20,000 leaves room for the largest single test."""
+    yield
+    if os.path.exists(_MAPS):
+        with open(_MAPS) as f:
+            n_maps = sum(1 for _ in f)
+        if n_maps > _MAPS_HIGH_WATER:
+            import jax
+
+            jax.clear_caches()
+
 
 # ---------------------------------------------------------------------------
 # hypothesis guard: the property tests import `hypothesis` at module scope, so
