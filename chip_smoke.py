@@ -13,11 +13,15 @@ the entry points a user calls: ``corpus_backend`` → ``ktree.build`` →
 count is cut, and each cut is printed. Every phase checks:
 
 - the tree's structural invariants;
+- the phase's Pallas kernels, run on the chip on real corpus rows against
+  the built tree's root centres, against the ``kernels/ref.py`` oracles run
+  on the host's CPU: ids equal except ties within f32 tolerance, distances
+  and scores within it;
 - every returned distance against the float64 distance recomputed on the
   host from the corpus rows;
-- recall@k against host brute force, no lower than the same phase gives with
-  JAX on the CPU (same seed, same size; ``tools/smoke_cpu_reference.py``)
-  less ``RECALL_MARGIN``;
+- recall@k against host brute force, no lower than the same search on the
+  same tree gives through the oracle path on the host's CPU (in this
+  process, under ``jax.default_device``) less ``RECALL_MARGIN``;
 - engine answers against the offline answers on the same chip, and that no
   engine request failed, timed out or was shed;
 - that the compiled build and search steps call each Pallas kernel the
@@ -43,8 +47,6 @@ import re
 import shutil
 import sys
 import time
-from typing import Optional
-
 import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
@@ -54,12 +56,13 @@ K, BEAM = 10, 4
 DIST_RTOL = 1e-5
 """f32 tolerance of a returned squared distance, relative to ‖q‖² + ‖x‖²
 (a one-pass bf16 matmul is off by ~1e-3 of that)."""
-RECALL_MARGIN = 0.03
-"""Recall@K the chip may lose against the CPU run of a phase. Its f32
-rounding differs, so the chip builds a different tree (other splits),
-not a worse one: rebuilding one corpus under other build keys on the CPU
-moves recall@10 by a standard deviation of 0.005 (RP, 50k docs, 256
-queries)."""
+RECALL_MARGIN = 0.01
+"""Recall@K the chip's search may lose against the oracle path's search of
+the same tree on the host's CPU. Both score in f32 and differ only in
+summation order (the oracle check bounds the difference of each distance),
+so their descents part only where two centres tie within that bound; a
+kernel that picks wrong centres loses far more."""
+ORACLE_ROWS = 256  # corpus rows of the kernel-vs-oracle check (one build batch)
 ROW_BLOCK = 4096  # corpus rows per host brute-force block
 KERNEL_RE = re.compile(r"\b(nn_assign|nn_topk|ell_spmm)_pallas")
 
@@ -73,7 +76,6 @@ class Phase:
     cut: str                   # why n_docs is below the source's
     build_kernels: tuple       # Pallas kernels the build step must call
     search_kernels: tuple      # ... and the search step
-    cpu_recall: Optional[float]  # recall@K of this phase with JAX on the CPU
     store: bool = False        # also answer the queries from a CorpusStore
 
 
@@ -81,22 +83,27 @@ PHASES = {
     "rp": Phase(
         "rp", "ktree-rcv1-rp", 193_844, 256,
         "none: the paper's full RCV1 subset",
-        ("nn_assign",), ("nn_topk",), cpu_recall=None,
+        ("nn_assign",), ("nn_topk",),
     ),
     "dense": Phase(
         "dense", "ktree-inex", 20_000, 512,
         "114,366 → 20,000 docs: the tree holds a dense f32 copy of every doc "
         "(ROADMAP R1); 7,232 nodes × 21 × 8000 × 4 B ≈ 4.9 GB of centres",
-        ("nn_assign",), ("nn_topk",), cpu_recall=None,
+        ("nn_assign",), ("nn_topk",),
     ),
     "ell": Phase(
         "ell", "ktree-rcv1", 20_000, 512,
         "193,844 → 20,000 docs: medoid centres are dense rows too "
         "(ROADMAP R1); ≈ 4.9 GB of centres",
-        ("ell_spmm",), ("ell_spmm",), cpu_recall=None, store=True,
+        ("ell_spmm",), ("ell_spmm",), store=True,
     ),
 }
-SHARDED_DOCS = 8_000  # --chips 4: corpus size of both sharded comparisons
+SHARDED_DOCS = 8_000
+"""--chips 4: corpus size of both sharded comparisons. The four-chip phase
+checks placement, the collectives and the cross-shard merge, whose code and
+per-row shapes (d = 8000, ELL nnz_max) do not depend on the doc count;
+8,000 docs give each of four shards 2,000 rows, several store blocks each.
+The one-chip phases cover the sizes."""
 
 
 class SmokeFailure(Exception):
@@ -189,6 +196,102 @@ def check_kernels(label: str, found, want) -> None:
         check(found[name] >= 1, f"{label} step has no {name} Pallas kernel")
 
 
+def host_cpu():
+    import jax
+
+    return jax.devices("cpu")[0]
+
+
+def same_as_oracle(label: str, got, want, d64: np.ndarray, scale: np.ndarray) -> None:
+    """A kernel's (ids, distances) against its oracle's: distances within
+    ``DIST_RTOL`` of ``scale`` (per row), and equal ids except where the
+    float64 distances ``d64`` [B, K] of the two ids tie within that bound."""
+    (ids, dist), (ids_r, dist_r) = got, want
+    ids, ids_r = ids.reshape(len(d64), -1), ids_r.reshape(len(d64), -1)
+    dist = dist.reshape(ids.shape).astype(np.float64)
+    dist_r = dist_r.reshape(ids.shape).astype(np.float64)
+    tol = DIST_RTOL * scale[:, None] + 1e-7
+    both_inf = np.isinf(dist) & np.isinf(dist_r)
+    err = np.abs(np.where(both_inf, 0.0, dist) - np.where(both_inf, 0.0, dist_r))
+    pick = lambda i: np.take_along_axis(d64, np.maximum(i, 0), axis=1)
+    tie = (ids >= 0) & (ids_r >= 0) & (np.abs(pick(ids) - pick(ids_r)) <= tol)
+    differ = ids != ids_r
+    log(f"  oracle {label}: {int(differ.sum())} of {ids.size} ids differ "
+        f"({int((differ & tie).sum())} tied), max |Δdist| / scale "
+        f"{float((err / scale[:, None]).max()):.2e}")
+    check((err <= tol).all(), f"oracle {label}: distances differ")
+    check((~differ | tie).all(), f"oracle {label}: ids differ beyond ties")
+
+
+def check_oracles(tree, be) -> None:
+    """Run the phase's Pallas kernels on the chip on its first
+    ``ORACLE_ROWS`` corpus rows (in the space the tree routes in) against
+    the built tree's root centres, and hold them to the ``kernels/ref.py``
+    oracles run on the host's CPU, and to float64 distances."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.backend import EllSparseBackend
+    from repro.kernels import ops, ref
+
+    cpu = host_cpu()
+    root = int(tree.root)
+    c = tree.centers[root]
+    valid = jnp.arange(tree.slots) < tree.n_entries[root]
+    rows = jnp.arange(min(ORACLE_ROWS, be.n_docs), dtype=jnp.int32)
+    x = be.take(rows)
+    on_cpu = lambda *a: jax.device_put(a, cpu)
+    x64, c64 = np.asarray(x, np.float64), np.asarray(c, np.float64)
+    v = np.asarray(valid)
+    d64 = ((x64[:, None, :] - c64[None]) ** 2).sum(-1)
+    d64 = np.where(v[None, :], d64, np.inf)
+    scale = (x64 ** 2).sum(-1) + (c64[v] ** 2).sum(-1).max()
+    log(f"  oracle check: {len(x64)} rows vs the root's {int(v.sum())} centres "
+        f"(d = {c64.shape[1]})")
+    if isinstance(be, EllSparseBackend):
+        vals, cols = be.values[rows], be.cols[rows]
+        s = np.asarray(ops.ell_spmm(vals, cols, c), np.float64)
+        s_ref = np.asarray(ref.ell_spmm_ref(*on_cpu(vals, cols, c)), np.float64)
+        s64 = x64 @ c64.T
+        for name, got in (("ell_spmm vs ref", s_ref), ("ell_spmm vs float64", s64)):
+            err = np.abs(s - got)
+            log(f"  oracle {name}: max |ΔS| / scale "
+                f"{float((err / scale[:, None]).max()):.2e}")
+            check((err <= DIST_RTOL * scale[:, None]).all(),
+                  f"oracle {name}: scores differ")
+        idx, dist = ops.medoid_assign_sparse(vals, cols, be.row_sq(rows), c, valid)
+        want = ref.nn_assign_ref(*on_cpu(x, c, valid))
+        same_as_oracle("medoid_assign_sparse (ell_spmm) vs ref",
+                       (np.asarray(idx), np.asarray(dist)),
+                       tuple(map(np.asarray, want)), d64, scale)
+        return
+    got = ops.nn_assign(x, c, valid)
+    want = ref.nn_assign_ref(*on_cpu(x, c, valid))
+    same_as_oracle("nn_assign vs ref", tuple(map(np.asarray, got)),
+                   tuple(map(np.asarray, want)), d64, scale)
+    for kq in (BEAM, K):
+        got = ops.nn_topk(x, c, kq, valid)
+        xc, cc, vc = on_cpu(x, c, valid)
+        want = ref.nn_topk_ref(xc, cc, kq, vc)
+        same_as_oracle(f"nn_topk kq={kq} vs ref", tuple(map(np.asarray, got)),
+                       tuple(map(np.asarray, want)), d64, scale)
+
+
+def oracle_search(tree, be, q, rp: bool):
+    """The phase's search of the same tree through the oracle path on the
+    host's CPU: inside a ``jax.default_device`` scope the backends call the
+    ``kernels/ref.py`` oracles, and every operand is moved to the CPU."""
+    import jax
+
+    from repro.core.query import topk_search
+
+    cpu = host_cpu()
+    with jax.default_device(cpu):
+        tree_c, be_c, q_c = jax.device_put((tree, be, q), cpu)
+        return topk_search(tree_c, q_c, k=K, beam=BEAM,
+                           rp=be_c if rp else None)
+
+
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
@@ -261,8 +364,8 @@ def run_engine(label: str, search_fn, x_q: np.ndarray, seed: int) -> None:
                   np.concatenate([o[1] for o in off])))
 
 
-def run_phase(phase: Phase, seed: int, on_chip: bool = True) -> float:
-    """Run one one-chip phase; returns its recall@K."""
+def run_phase(phase: Phase, seed: int) -> None:
+    """Run one one-chip phase and its checks."""
     import jax
     import jax.numpy as jnp
 
@@ -293,6 +396,7 @@ def run_phase(phase: Phase, seed: int, on_chip: bool = True) -> float:
         f"{tree.centers.nbytes / 1e9:.2f} GB")
     kt.check_invariants(tree, n_docs=n)
     log("  check_invariants: ok")
+    check_oracles(tree, be)
 
     nq = phase.n_queries
     q = query_source(base, nq)
@@ -306,18 +410,20 @@ def run_phase(phase: Phase, seed: int, on_chip: bool = True) -> float:
     check_distances(f"{phase.name} search", x_q, docs, dist, base)
 
     t0 = time.perf_counter()
+    ref_docs, _ = oracle_search(tree, be, q, rp=rep == "rp")
+    log(f"  oracle search on the host CPU: {time.perf_counter() - t0:.1f} s "
+        f"with compile; {int((ref_docs != docs).any(axis=1).sum())} of {nq} "
+        "queries answered differently from the chip")
+    t0 = time.perf_counter()
     truth = truth_topk(x_q, base, n)
     recall = qm.recall_at_k(docs, truth)
-    ref = ("not measured" if phase.cpu_recall is None
-           else f"{phase.cpu_recall:.4f}, margin {RECALL_MARGIN}")
+    recall_ref = qm.recall_at_k(ref_docs, truth)
     log(f"  recall@{K} = {recall:.4f} vs host brute force "
-        f"({time.perf_counter() - t0:.1f} s); CPU run of this phase: {ref}")
-    if not on_chip:
-        return recall  # the CPU reference run needs the recall only
-    if phase.cpu_recall is not None:
-        check(recall >= phase.cpu_recall - RECALL_MARGIN,
-              f"{phase.name}: recall {recall:.4f} below the CPU run's "
-              f"{phase.cpu_recall:.4f} − {RECALL_MARGIN}")
+        f"({time.perf_counter() - t0:.1f} s); oracle search on the host CPU "
+        f"{recall_ref:.4f}, margin {RECALL_MARGIN}")
+    check(recall >= recall_ref - RECALL_MARGIN,
+          f"{phase.name}: recall {recall:.4f} below the oracle search's "
+          f"{recall_ref:.4f} − {RECALL_MARGIN}")
 
     levels = int(tree.depth) - 1
     mlev = kt._levels_bucket(levels)
@@ -351,7 +457,6 @@ def run_phase(phase: Phase, seed: int, on_chip: bool = True) -> float:
         "(smoke wall time, not a metric)")
     del tree, be, base, q, search_fn
     gc.collect()
-    return recall
 
 
 def fresh_dir(name: str) -> str:

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_tpu
 from repro.kernels.ref import EXACT
 from repro.sparse.csr import Csr, csr_from_dense, csr_row_gather_dense
 from repro.sparse.ell import Ell, ell_from_csr
@@ -68,8 +69,9 @@ def _use_pallas() -> bool:
     """Kernel dispatch: the Pallas kernels are compiled on TPU; elsewhere the
     pure-jnp oracles in :mod:`repro.kernels.ref` serve as the fallback (the
     kernels themselves stay testable off-TPU through interpret mode in
-    :mod:`repro.kernels.ops`)."""
-    return jax.default_backend() == "tpu"
+    :mod:`repro.kernels.ops`). Follows ``jax.default_device`` scopes
+    (:func:`repro.kernels.on_tpu`)."""
+    return on_tpu()
 
 
 @jax.tree_util.register_dataclass

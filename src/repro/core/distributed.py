@@ -136,7 +136,10 @@ def distributed_kmeans(
     step = distributed_lloyd_step(mesh, use_kernel=use_kernel)
     idx = sse = None
     for _ in range(iters):
-        centers, idx, sse = step(x, centers)
+        # one step in flight at a time: XLA:CPU's in-process all-reduce can
+        # deadlock when two steps' collectives wait on the same thread pool
+        # (host-device meshes); on an accelerator it costs a round trip
+        centers, idx, sse = jax.block_until_ready(step(x, centers))
     return centers, idx, sse
 
 

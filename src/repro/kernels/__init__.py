@@ -4,6 +4,8 @@ nearest-centre search (``nn_assign``), its top-k generalisation
 dispatches them; ``ref`` holds the pure-jnp oracles."""
 from __future__ import annotations
 
+import jax
+
 _MIB = 1 << 20
 DEFAULT_SCOPED_VMEM = 16 * _MIB
 """Mosaic's default scoped-VMEM limit per kernel on v5e (the core has
@@ -19,3 +21,17 @@ def scoped_vmem_limit(pipelined_bytes: int, resident_bytes: int = 0) -> int:
     over the default."""
     need = 2 * pipelined_bytes + resident_bytes + 8 * _MIB
     return max(DEFAULT_SCOPED_VMEM, -(-need // _MIB) * _MIB)
+
+
+def on_tpu() -> bool:
+    """Whether the computation being traced runs on a TPU: the platform of an
+    enclosing ``jax.default_device`` scope, else the default backend's. It
+    picks the Pallas kernels over the ``ref`` oracles, and compiled over
+    interpret mode. ``jax_default_device`` is part of the jit cache key, so
+    a trace made under one scope is never reused under another: on a TPU
+    host, ``with jax.default_device(jax.devices("cpu")[0])`` runs the oracle
+    path on the host's CPU."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend() == "tpu"
+    return (dev if isinstance(dev, str) else dev.platform) == "tpu"

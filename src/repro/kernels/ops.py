@@ -9,6 +9,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.nn_assign import nn_assign_pallas
 from repro.kernels.nn_topk import nn_topk_pallas
 from repro.kernels.ell_spmm import ell_spmm_pallas
@@ -16,7 +17,7 @@ from repro.kernels.ref import EXACT
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def _pad_to(n: int, mult: int) -> int:

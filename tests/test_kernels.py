@@ -239,6 +239,22 @@ def test_kernel_flag_in_kmeans():
     np.testing.assert_allclose(np.asarray(d1), np.asarray(d2), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("scope", ["device", "platform"])
+def test_dispatch_follows_default_device_scope(monkeypatch, scope):
+    """On a TPU host the kernels run unless a ``jax.default_device`` scope
+    names the CPU: then the backends call the oracles, as the oracle search
+    of ``chip_smoke.py`` relies on."""
+    from repro.core.backend import _use_pallas
+    from repro.kernels import on_tpu
+    assert not on_tpu() and ops._interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert on_tpu() and _use_pallas() and not ops._interpret()
+    cpu = jax.devices("cpu")[0] if scope == "device" else "cpu"
+    with jax.default_device(cpu):
+        assert not on_tpu() and not _use_pallas() and ops._interpret()
+    assert on_tpu()
+
+
 def test_topk_merge_ref_exact_union():
     """The serving merge oracle: merging per-shard top-k lists over disjoint id
     subsets equals a global top-k over the union; (−1, +inf) padding is
