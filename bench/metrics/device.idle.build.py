@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent: 1 − busy / window, busy being the union of the device op intervals."""
+
+
+def read(layer):
+    tr = layer.get("trace")
+    if tr is None or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -1,0 +1,10 @@
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+# the benchmark's tests compile on the CPU only; nothing goes to a cache
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
