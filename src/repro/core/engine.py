@@ -374,8 +374,14 @@ class ServingEngine:
     - ``clock`` — monotonic time source shared with the
       :class:`LatencyRecorder` (fake-clock seam for tests).
     - ``profiler`` — optional ``repro.core.profile.Profiler`` (DESIGN.md
-      §11): records one ``"engine_batch"`` span per dispatched batch and one
-      ``"engine_call"`` span per offline-engine call inside it; the default
+      §11). On the dispatcher thread it records ``"engine_wait"`` while the
+      queue is empty, ``"engine_fill"`` while queued requests wait for the
+      batch to fill or for the forcing point, one ``"engine_batch"`` span per
+      dispatched batch and one ``"engine_call"`` span per offline-engine
+      call inside it, both tagged with the batch number; and, per request,
+      an ``"engine_queue"`` record from admission to the moment its batch is
+      popped, on this engine's ``clock`` and with the batch's tag (timed
+      afterwards, so absent from a device trace). The default
       ``NULL_PROFILER`` is free.
     - ``request_timeout_s`` — engine-wide per-request time budget (admit →
       answer), enforced by the watchdog thread: an overdue request — still
@@ -455,6 +461,7 @@ class ServingEngine:
         self._watchdog_restarts = 0
         self._degraded = 0
         self._n_batches = 0
+        self._batch_tag = -1  # number of the batch last popped (dispatcher only)
         self._n_fragments = 0
         self._occupancy_sum = 0.0
         self._max_queue_depth = 0
@@ -523,13 +530,19 @@ class ServingEngine:
     # ------------------------------------------------------------- dispatch
     def _take_batch(self) -> List[_Pending]:
         """Pop FIFO requests up to ``row_budget`` rows (caller holds the
-        lock; always pops at least one)."""
+        lock; always pops at least one), number the batch, and record each
+        request's ``"engine_queue"`` wait under that number."""
         batch: List[_Pending] = [self._queue.popleft()]
         rows = batch[0].rows.shape[0]
         while self._queue and rows + self._queue[0].rows.shape[0] <= self.row_budget:
             nxt = self._queue.popleft()
             rows += nxt.rows.shape[0]
             batch.append(nxt)
+        self._batch_tag += 1
+        if self.profiler.enabled:
+            now = self.recorder.now()
+            for p in batch:
+                self.profiler.add("engine_queue", p.t_admit, now, tag=self._batch_tag)
         return batch
 
     def _loop(self) -> None:
@@ -542,21 +555,24 @@ class ServingEngine:
         handles that would otherwise hang."""
         while True:
             with self._cv:
-                while not self._queue:
-                    if self._closing or self._abort:
-                        return
-                    self._cv.wait(0.05)
+                if not self._queue:
+                    with self.profiler.span("engine_wait"):
+                        while not self._queue:
+                            if self._closing or self._abort:
+                                return
+                            self._cv.wait(0.05)
                 # wait for the batch to fill — but never past the oldest
                 # pending request's forcing point (the watchdog may expire
                 # queued requests concurrently, so re-check for emptiness)
-                while self._queue:
-                    total = sum(p.rows.shape[0] for p in self._queue)
-                    force_t = min(p.force_t for p in self._queue)
-                    now = self.recorder.now()
-                    if (total >= self.row_budget or now >= force_t
-                            or self._closing or self._abort):
-                        break
-                    self._cv.wait(min(max(force_t - now, 0.0), 0.05))
+                with self.profiler.span("engine_fill"):
+                    while self._queue:
+                        total = sum(p.rows.shape[0] for p in self._queue)
+                        force_t = min(p.force_t for p in self._queue)
+                        now = self.recorder.now()
+                        if (total >= self.row_budget or now >= force_t
+                                or self._closing or self._abort):
+                            break
+                        self._cv.wait(min(max(force_t - now, 0.0), 0.05))
                 if self._abort:
                     return
                 if not self._queue:
@@ -659,7 +675,7 @@ class ServingEngine:
         engines (``on_fault="degrade"``) return a third
         :class:`repro.core.faults.FaultReport` element; plain engines get
         ``report=None``."""
-        with self.profiler.span("engine_call"):
+        with self.profiler.span("engine_call", tag=self._batch_tag):
             if chunk_rows is not None and self._accepts_chunk:
                 out = self.search_fn(x, k, beam, chunk_rows=chunk_rows)
             else:
@@ -741,7 +757,7 @@ class ServingEngine:
         for c in self.block_caches:
             c.reset_peak()
         n_frags = 0
-        batch_span = self.profiler.span("engine_batch")
+        batch_span = self.profiler.span("engine_batch", tag=self._batch_tag)
         batch_span.__enter__()
         try:
             for (k, beam, bucket), group in self._fragments(batch).items():

@@ -586,35 +586,72 @@ def _split_batch_size(n: int) -> int:
     return min(b, _SPLIT_BATCH_CAP)
 
 
-def _split_all_overflowing(tree: KTree, key: jax.Array) -> Tuple[KTree, jax.Array]:
+def _split_all_overflowing(
+    tree: KTree, key: jax.Array, profiler=NULL_PROFILER
+) -> Tuple[KTree, jax.Array]:
     """Host control plane: split overflowing nodes shallowest (max height)
     first — all overflowing nodes of one height in a single jitted call — until
     the m-order invariant holds everywhere. Splitting top-down guarantees a
     parent has spare capacity before its children promote into it (splits that
-    would overflow a full parent are deferred one round by the batch op)."""
-    while True:
-        n_nodes = int(tree.n_nodes)
-        # slice on the host: a device slice of a new length compiles anew
-        n_entries = np.asarray(tree.n_entries)[:n_nodes]
-        over = np.nonzero(n_entries > tree.order)[0]
-        if over.size == 0:
-            return tree, key
-        root = int(tree.root)
-        if n_entries[root] > tree.order:
-            # the root split grows the tree — scalar path
-            key, sub = jax.random.split(key)
-            tree = split_node(tree, jnp.int32(root), sub)
-            continue
-        heights = np.asarray(tree.height)[over]
-        batch = over[heights == heights.max()][:_SPLIT_BATCH_CAP]
-        size = _split_batch_size(batch.size)
-        ids = np.zeros(size, np.int32)
-        ids[: batch.size] = batch[:size]
-        valid = np.arange(size) < batch.size
-        key, sub = jax.random.split(key)
-        tree = split_nodes_batch(
-            tree, jnp.asarray(ids), jnp.asarray(valid), jax.random.split(sub, size)
-        )
+    would overflow a full parent are deferred one round by the batch op).
+
+    ``profiler`` records the cascade as one ``"split_cascade"`` span and each
+    round as a ``"split_scan"`` span (its device→host reads) followed by a
+    ``"split_round"`` span (the split program's dispatch)."""
+    with profiler.span("split_cascade"):
+        while True:
+            with profiler.span("split_scan"):
+                n_nodes = int(tree.n_nodes)
+                # slice on the host: a device slice of a new length compiles anew
+                n_entries = np.asarray(tree.n_entries)[:n_nodes]
+                over = np.nonzero(n_entries > tree.order)[0]
+                if over.size == 0:
+                    return tree, key
+                root = int(tree.root)
+                root_split = n_entries[root] > tree.order
+                if not root_split:
+                    heights = np.asarray(tree.height)[over]
+            with profiler.span("split_round"):
+                if root_split:
+                    # the root split grows the tree — scalar path
+                    key, sub = jax.random.split(key)
+                    tree = split_node(tree, jnp.int32(root), sub)
+                    continue
+                batch = over[heights == heights.max()][:_SPLIT_BATCH_CAP]
+                size = _split_batch_size(batch.size)
+                ids = np.zeros(size, np.int32)
+                ids[: batch.size] = batch[:size]
+                valid = np.arange(size) < batch.size
+                key, sub = jax.random.split(key)
+                tree = split_nodes_batch(
+                    tree, jnp.asarray(ids), jnp.asarray(valid),
+                    jax.random.split(sub, size),
+                )
+
+
+def _insert_batch(
+    tree: KTree, be, rows: jax.Array, doc_ids: jax.Array, valid_np: np.ndarray,
+    key: jax.Array, profiler=NULL_PROFILER,
+) -> Tuple[KTree, jax.Array]:
+    """Insert one batch of backend rows: insertion waves, each followed by
+    the split cascade, until every ``valid_np`` row is accepted. The one wave
+    loop of :func:`build`, :func:`build_from_store` and :func:`insert`;
+    ``profiler`` records it as a ``"build_batch"`` span holding one
+    ``"insert_wave"`` span per wave (with its depth and ``accepted`` reads)
+    and the cascade's spans (:func:`_split_all_overflowing`). The pending set
+    between waves is derived from the fetched ``accepted`` mask — no extra
+    device→host sync per wave."""
+    with profiler.span("build_batch"):
+        while valid_np.any():
+            with profiler.span("insert_wave"):
+                levels = int(tree.depth) - 1
+                tree, accepted = _insert_wave(
+                    tree, be, rows, doc_ids, jnp.asarray(valid_np),
+                    jnp.int32(levels), max_levels=_levels_bucket(levels),
+                )
+                valid_np = valid_np & ~np.asarray(accepted)
+            tree, key = _split_all_overflowing(tree, key, profiler)
+    return tree, key
 
 
 # ---------------------------------------------------------------------------
@@ -629,6 +666,7 @@ def build(
     medoid: bool = False,
     max_nodes: Optional[int] = None,
     backend: str = "auto",
+    profiler=NULL_PROFILER,
 ) -> KTree:
     """Online batched construction (paper §1 semantics; ``batch_size=1`` is the
     exact sequential algorithm). Host loop: waves of route→accept→insert, then
@@ -641,8 +679,8 @@ def build(
     input. A prebuilt ``backend.RandomProjBackend`` passes through and builds
     the Random Indexing tree (DESIGN.md §5.1): every wave routes, appends,
     and splits in the projected space, so ``tree.dim`` is the projection's
-    ``out_dim``. The pending set between waves is derived from the fetched
-    ``accepted`` mask — no extra device→host sync per wave."""
+    ``out_dim``. ``profiler=`` records each batch's spans
+    (:func:`_insert_batch`); the default ``NULL_PROFILER`` is free."""
     be = make_backend(x, backend)
     n = be.n_docs
     if key is None:
@@ -656,16 +694,9 @@ def build(
         pad = batch_size - idx.size
         ids_np = np.concatenate([idx, np.full(pad, -1)]).astype(np.int32)
         rows = jnp.asarray(np.where(ids_np >= 0, ids_np, 0))
-        doc_ids = jnp.asarray(ids_np)
-        valid_np = ids_np >= 0
-        while valid_np.any():
-            levels = int(tree.depth) - 1
-            tree, accepted = _insert_wave(
-                tree, be, rows, doc_ids, jnp.asarray(valid_np),
-                jnp.int32(levels), max_levels=_levels_bucket(levels),
-            )
-            valid_np &= ~np.asarray(accepted)
-            tree, key = _split_all_overflowing(tree, key)
+        tree, key = _insert_batch(
+            tree, be, rows, jnp.asarray(ids_np), ids_np >= 0, key, profiler
+        )
     return tree
 
 
@@ -716,8 +747,8 @@ def build_from_store(
     ``prefetch=None`` resolves through ``tuned=`` (a ``TunedKnobs`` from the
     store's ``TUNE.json`` sidecar, DESIGN.md §11) and then the repo default
     0 — explicit values win, and the knob never changes the tree.
-    ``profiler=`` records one ``"read"`` span per batch fetch and one
-    ``"insert"`` span per batch's insert waves."""
+    ``profiler=`` records one ``"read"`` span per batch fetch and the
+    spans of :func:`build` for each batch's insertion."""
     from repro.core.backend import RandomProjBackend, backend_from_rows
 
     _, _, prefetch = resolve_knobs(tuned, prefetch=prefetch)
@@ -725,7 +756,7 @@ def build_from_store(
         be = RandomProjBackend.from_store(store, projection, prefetch=prefetch)
         return build(
             be, order=order, key=key, batch_size=batch_size, medoid=medoid,
-            max_nodes=max_nodes,
+            max_nodes=max_nodes, profiler=profiler,
         )
     n = store.n_docs
     if key is None:
@@ -760,29 +791,23 @@ def build_from_store(
             fetched = ((ids_np, fetch(ids_np)) for ids_np in batches)
         for ids_np, got in fetched:
             be = backend_from_rows(store, got)
-            rows = jnp.arange(batch_size, dtype=jnp.int32)
-            doc_ids = jnp.asarray(ids_np)
-            valid_np = ids_np >= 0
-            with profiler.span("insert"):
-                while valid_np.any():
-                    levels = int(tree.depth) - 1
-                    tree, accepted = _insert_wave(
-                        tree, be, rows, doc_ids, jnp.asarray(valid_np),
-                        jnp.int32(levels), max_levels=_levels_bucket(levels),
-                    )
-                    valid_np &= ~np.asarray(accepted)
-                    tree, key = _split_all_overflowing(tree, key)
+            tree, key = _insert_batch(
+                tree, be, jnp.arange(batch_size, dtype=jnp.int32),
+                jnp.asarray(ids_np), ids_np >= 0, key, profiler,
+            )
     return tree
 
 
 def insert(
-    tree: KTree, x, doc_ids, key: Optional[jax.Array] = None
+    tree: KTree, x, doc_ids, key: Optional[jax.Array] = None,
+    profiler=NULL_PROFILER,
 ) -> KTree:
     """Incremental insertion into an existing tree (paper §5: "clusters can be
     produced incrementally ... easy updates as new documents arrive").
 
     ``x``: the new documents (dense array, Csr, or backend); ``doc_ids``: their
-    global ids (−1 = padding)."""
+    global ids (−1 = padding), inserted as one batch whose spans ``profiler``
+    records as :func:`build` does."""
     if key is None:
         key = jax.random.PRNGKey(1)
     # the waves and splits donate their tree: work on a copy, the caller's
@@ -790,16 +815,10 @@ def insert(
     tree = jax.tree_util.tree_map(jnp.copy, tree)
     be = make_backend(x)
     doc_ids = jnp.asarray(doc_ids, jnp.int32)
-    rows = jnp.arange(be.n_docs, dtype=jnp.int32)
-    valid_np = np.asarray(doc_ids) >= 0
-    while valid_np.any():
-        levels = int(tree.depth) - 1
-        tree, accepted = _insert_wave(
-            tree, be, rows, doc_ids, jnp.asarray(valid_np),
-            jnp.int32(levels), max_levels=_levels_bucket(levels),
-        )
-        valid_np &= ~np.asarray(accepted)
-        tree, key = _split_all_overflowing(tree, key)
+    tree, _ = _insert_batch(
+        tree, be, jnp.arange(be.n_docs, dtype=jnp.int32), doc_ids,
+        np.asarray(doc_ids) >= 0, key, profiler,
+    )
     return tree
 
 

@@ -26,6 +26,7 @@ from repro.core.engine import (
     ServingEngine,
     make_search_fn,
 )
+from repro.core.profile import Profiler
 from repro.core.query import AnswerCache, topk_search
 from repro.launch.engine import (
     open_loop_arrivals,
@@ -289,6 +290,60 @@ def test_engine_waits_to_fill_until_forcing_point():
     assert st["n_batches"] == 1 and st["completed"] == 2
     _assert_bit_identical(r1, _offline(tree, q[0:1], 4, 2))
     _assert_bit_identical(r2, _offline(tree, q[1:2], 4, 2))
+
+
+def _until(cond, timeout=10.0):
+    t0 = time.monotonic()
+    while not cond():
+        assert time.monotonic() - t0 < timeout, "condition never held"
+        time.sleep(0.005)
+
+
+def test_engine_spans_on_fake_clock():
+    """The dispatcher's spans on the engine's fake clock: ``engine_wait``
+    until a request comes, ``engine_fill`` until the batch fills, one
+    ``engine_queue`` record per request from admission to its batch's pop,
+    and ``engine_batch``/``engine_call`` — all three under the batch's
+    number."""
+    clk = FakeClock()
+    prof = Profiler(clock=clk)
+
+    def fn(x, k, beam):  # a call takes 2 s of the fake clock
+        clk.advance(2.0)
+        return np.zeros((len(x), k), np.int32), np.zeros((len(x), k), np.float32)
+
+    def named(name):
+        return [(r.t0, r.t1, r.tag) for r in prof.records if r.name == name]
+
+    row = np.zeros((1, 3), np.float32)
+    eng = ServingEngine(fn, row_budget=2, max_queue=8, max_wait_s=1e9,
+                        clock=clk, profiler=prof)
+    try:
+        clk.t = 1.0
+        h1 = eng.submit(row, k=2, beam=1)
+        _until(lambda: named("engine_wait"))
+        time.sleep(0.05)  # the dispatcher now waits for the batch to fill
+        clk.t = 3.0
+        h2 = eng.submit(row, k=2, beam=1)
+        h1.result(timeout=10), h2.result(timeout=10)
+        _until(lambda: named("engine_batch"))
+        clk.t = 6.0
+        hs = [eng.submit(row, k=2, beam=1) for _ in range(2)]
+        for h in hs:
+            h.result(timeout=10)
+        _until(lambda: len(named("engine_batch")) == 2)
+    finally:
+        eng.close()
+    assert sorted(named("engine_queue")) == [
+        (1.0, 3.0, 0), (3.0, 3.0, 0), (6.0, 6.0, 1), (6.0, 6.0, 1)]
+    assert named("engine_batch") == [(3.0, 5.0, 0), (6.0, 8.0, 1)]
+    calls = [r for r in prof.records if r.name == "engine_call"]
+    assert [(r.t0, r.t1, r.tag, r.parent) for r in calls] == [
+        (3.0, 5.0, 0, "engine_batch"), (6.0, 8.0, 1, "engine_batch")]
+    assert named("engine_fill")[0] == (1.0, 3.0, None)
+    waits = named("engine_wait")
+    assert waits[0][1] == 1.0 and waits[1][1] == 6.0
+    assert all(r.depth == 0 for r in prof.records if r.name != "engine_call")
 
 
 # ------------------------------------------------------------ cache staging

@@ -1,3 +1,5 @@
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -56,6 +58,39 @@ def test_incremental_insert():
     tree = kt.build(x[:100], order=8, batch_size=32)
     tree = kt.insert(tree, x[100:132], jnp.arange(100, 132))
     kt.check_invariants(tree, n_docs=132)
+
+
+def test_profiled_build_equals_plain_and_counts_waves(monkeypatch):
+    """A build handed a Profiler returns the tree of one without, field for
+    field, and records one ``insert_wave`` span per wave it runs, inside one
+    ``build_batch`` span per batch."""
+    from repro.core.profile import Profiler
+
+    rng = np.random.default_rng(4)
+    x, _ = planted(rng, k=4, per=40)
+    plain = kt.build(x, order=4, batch_size=32, key=jax.random.PRNGKey(3))
+    waves = []
+    wave = kt._insert_wave
+
+    def counted(*a, **kw):
+        waves.append(1)
+        return wave(*a, **kw)
+    monkeypatch.setattr(kt, "_insert_wave", counted)
+    prof = Profiler()
+    tree = kt.build(x, order=4, batch_size=32, key=jax.random.PRNGKey(3),
+                    profiler=prof)
+    for f in dataclasses.fields(kt.KTree):
+        a, b = getattr(plain, f.name), getattr(tree, f.name)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f.name)
+    tot = prof.totals()
+    assert tot["insert_wave"]["count"] == len(waves) > tot["build_batch"]["count"]
+    assert tot["build_batch"]["count"] == 5  # 160 docs in batches of 32
+    assert tot["split_cascade"]["count"] == len(waves)
+    assert tot["split_scan"]["count"] == tot["split_round"]["count"] + len(waves)
+    parents = {r.name: r.parent for r in prof.records}
+    assert parents == {"build_batch": None, "insert_wave": "build_batch",
+                       "split_cascade": "build_batch", "split_scan": "split_cascade",
+                       "split_round": "split_cascade"}
 
 
 def test_nn_search_quality():

@@ -1,7 +1,9 @@
-"""Phase-span profiler (DESIGN.md §11): span exactness and nesting on an
-injected fake clock, cross-thread interval merging + read∩compute overlap,
-and the disabled-mode contract (``NULL_PROFILER`` hands out one shared no-op
-span and records nothing — the hot paths rely on that being free)."""
+"""Phase-span profiler (DESIGN.md §11): span exactness, nesting, parents and
+tags on an injected fake clock, cross-thread interval merging +
+read∩compute overlap, and the disabled-mode contract (``NULL_PROFILER`` hands
+out one shared no-op span and records nothing — the hot paths rely on that
+being free). That an enabled span lands on a device trace's host plane, and
+a null one does not, is pinned in bench/tests/test_bench_trace.py."""
 import threading
 
 import pytest
@@ -91,6 +93,48 @@ def test_depth_is_per_thread():
     assert depths == {"read": 0, "compute": 0}
 
 
+def test_parent_tag_and_max_on_fake_clock():
+    """A span's parent is the span open on its thread when it opened; its tag
+    is what the caller gave; totals keep the longest span per name."""
+    prof = Profiler(clock=FakeClock())
+    with prof.span("engine_batch", tag=7):          # t0 = 0
+        with prof.span("engine_call", tag=7):       # (1, 2)
+            pass
+        with prof.span("engine_call", tag=7):       # (3, 6)
+            with prof.span("compute"):              # (4, 5)
+                pass
+    prof.add("engine_queue", 10.0, 13.5, tag=7)
+    recs = {(r.name, r.t0): r for r in prof.records}
+    assert recs[("engine_call", 1.0)] == SpanRecord(
+        "engine_call", 1.0, 2.0, 1, "engine_batch", 7)
+    assert recs[("compute", 4.0)].parent == "engine_call"
+    assert recs[("compute", 4.0)].tag is None and recs[("compute", 4.0)].depth == 2
+    assert recs[("engine_batch", 0.0)].parent is None
+    assert recs[("engine_queue", 10.0)] == SpanRecord(
+        "engine_queue", 10.0, 13.5, 0, None, 7)
+    t = prof.totals()
+    assert t["engine_call"] == {"seconds": 4.0, "count": 2, "max_s": 3.0}
+    assert t["engine_batch"]["max_s"] == 7.0          # (0, 7)
+    assert t["engine_queue"]["max_s"] == 3.5
+
+
+def test_parent_is_per_thread():
+    """A span opened on a worker thread while the main thread holds one has
+    no parent: parents follow the thread, as depth does."""
+    prof = Profiler(clock=FakeClock())
+
+    def worker():
+        with prof.span("read"):
+            pass
+
+    with prof.span("compute"):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+    parents = {r.name: r.parent for r in prof.records}
+    assert parents == {"read": None, "compute": None}
+
+
 # ------------------------------------------------- totals / intervals
 
 def test_totals_and_reset():
@@ -99,8 +143,8 @@ def test_totals_and_reset():
     prof.add("read", 5.0, 6.0)
     prof.add("compute", 1.0, 4.0)
     t = prof.totals()
-    assert t["read"] == {"seconds": 3.0, "count": 2}
-    assert t["compute"] == {"seconds": 3.0, "count": 1}
+    assert t["read"] == {"seconds": 3.0, "count": 2, "max_s": 2.0}
+    assert t["compute"] == {"seconds": 3.0, "count": 1, "max_s": 3.0}
     prof.reset()
     assert prof.records == () and prof.totals() == {}
 
@@ -135,14 +179,6 @@ def test_overlap_zero_when_serialised():
         prof.add("read", 2 * i, 2 * i + 1)
         prof.add("compute", 2 * i + 1, 2 * i + 2)
     assert prof.overlap_seconds("read", "compute") == 0.0
-
-
-def test_phase_report_mentions_phases_and_overlap():
-    prof = Profiler()
-    prof.add("read", 0.0, 1.0)
-    prof.add("compute", 0.5, 1.5)
-    rep = prof.phase_report()
-    assert "read=" in rep and "compute=" in rep and "read∩compute=" in rep
 
 
 # --------------------------------------------------------- disabled mode
