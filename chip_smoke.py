@@ -26,7 +26,10 @@ count is cut, and each cut is printed. Every phase checks:
   engine request failed, timed out or was shed;
 - that the compiled build and search steps call each Pallas kernel the
   phase should use (``tpu_custom_call``), so no step fell back to the
-  ``kernels/ref.py`` oracles or to interpret mode.
+  ``kernels/ref.py`` oracles or to interpret mode;
+- that no compiled program that takes the tree (search, routing, the
+  insertion wave, both splits) copies the whole of ``tree.centers`` to
+  change its layout (DESIGN.md §3).
 
 The ELL phase also answers its queries from a ``CorpusStore`` written to a
 fresh directory, with a residency budget below the corpus size.
@@ -65,6 +68,8 @@ kernel that picks wrong centres loses far more."""
 ORACLE_ROWS = 256  # corpus rows of the kernel-vs-oracle check (one build batch)
 ROW_BLOCK = 4096  # corpus rows per host brute-force block
 KERNEL_RE = re.compile(r"\b(nn_assign|nn_topk|ell_spmm)_pallas")
+HLO_OP_RE = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(")
+"""An HLO instruction line: (result type, opcode)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,13 +93,13 @@ PHASES = {
     "dense": Phase(
         "dense", "ktree-inex", 20_000, 512,
         "114,366 → 20,000 docs: the tree holds a dense f32 copy of every doc "
-        "(ROADMAP R1); 7,232 nodes × 21 × 8000 × 4 B ≈ 4.9 GB of centres",
+        "(ROADMAP R1); 7,232 nodes × 24 slots × 8000 × 4 B ≈ 5.55 GB of centres",
         ("nn_assign",), ("nn_topk",),
     ),
     "ell": Phase(
         "ell", "ktree-rcv1", 20_000, 512,
         "193,844 → 20,000 docs: medoid centres are dense rows too "
-        "(ROADMAP R1); ≈ 4.9 GB of centres",
+        "(ROADMAP R1); ≈ 5.55 GB of centres",
         ("ell_spmm",), ("ell_spmm",), store=True,
     ),
 }
@@ -179,15 +184,39 @@ def same_answers(label: str, x_q, a, b) -> None:
     check((same_ids | close).all(), f"{label}: ids differ")
 
 
-def kernel_calls(jitted, *args, **kw) -> collections.Counter:
-    """Pallas kernels in the compiled program of ``jitted(*args, **kw)``."""
-    text = jitted.lower(*args, **kw).compile().as_text()
+def compiled_text(jitted, *args, **kw) -> str:
+    """The compiled HLO of ``jitted(*args, **kw)``."""
+    return jitted.lower(*args, **kw).compile().as_text()
+
+
+def kernel_calls(text: str) -> collections.Counter:
+    """Pallas kernels in compiled HLO ``text``."""
     found = collections.Counter()
     for line in text.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
             m = KERNEL_RE.search(line)
             found[m.group(1) if m else "other"] += 1
     return found
+
+
+def tree_copies(text: str, shape) -> int:
+    """``copy`` instructions in compiled HLO ``text`` whose result has the
+    stored tree's ``shape``: the whole of ``tree.centers`` moved to another
+    layout."""
+    dims = "[" + ",".join(map(str, shape)) + "]"
+    return sum(
+        1 for m in map(HLO_OP_RE.match, text.splitlines())
+        if m and m.group(2) in ("copy", "copy-start") and dims in m.group(1)
+    )
+
+
+def check_tree_copies(tree, texts: dict) -> None:
+    """Fail if a compiled program (``texts``: label → HLO) copies the tree."""
+    log(f"  tree centres {tree.centers.shape} stored as {tree.centers.format}")
+    for label, text in texts.items():
+        n = tree_copies(text, tree.centers.shape)
+        log(f"  {label}: {n} tree-sized copies")
+        check(n == 0, f"{label} copies the whole tree {n}x")
 
 
 def check_kernels(label: str, found, want) -> None:
@@ -236,7 +265,7 @@ def check_oracles(tree, be) -> None:
 
     cpu = host_cpu()
     root = int(tree.root)
-    c = tree.centers[root]
+    c = tree.node_centers(root)
     valid = jnp.arange(tree.slots) < tree.n_entries[root]
     rows = jnp.arange(min(ORACLE_ROWS, be.n_docs), dtype=jnp.int32)
     x = be.take(rows)
@@ -425,30 +454,41 @@ def run_phase(phase: Phase, seed: int) -> None:
           f"{phase.name}: recall {recall:.4f} below the oracle search's "
           f"{recall_ref:.4f} − {RECALL_MARGIN}")
 
-    levels = int(tree.depth) - 1
-    mlev = kt._levels_bucket(levels)
-    b = 256
-    check_kernels("build", kernel_calls(
-        kt._insert_wave, tree, be, jnp.arange(b, dtype=jnp.int32),
-        jnp.arange(b, dtype=jnp.int32), jnp.ones(b, bool),
-        jnp.int32(levels), max_levels=mlev,
-    ), phase.build_kernels)
+    levels = jnp.int32(int(tree.depth) - 1)
+    mlev = kt._levels_bucket(int(levels))
+    b, n_split = 256, 8
+    rows_b = jnp.arange(b, dtype=jnp.int32)
+    rows_q = jnp.arange(nq, dtype=jnp.int32)
+    key = jax.random.PRNGKey(seed)
     if rep == "rp":
-        zq = be.projection.apply(jnp.asarray(x_q))
-        found = kernel_calls(
-            qm._chunk_candidates_jit, tree, DenseBackend(zq),
-            jnp.arange(nq, dtype=jnp.int32), jnp.int32(levels),
-            max_levels=mlev, beam=BEAM,
-        )
+        qbe = DenseBackend(be.projection.apply(jnp.asarray(x_q)))
     else:
         from repro.core.backend import make_backend
 
-        found = kernel_calls(
-            qm._beam_search, tree, make_backend(q),
-            jnp.arange(nq, dtype=jnp.int32), jnp.int32(levels),
-            max_levels=mlev, beam=BEAM, k=K,
-        )
-    check_kernels("search", found, phase.search_kernels)
+        qbe = make_backend(q)
+    texts = {
+        "_insert_wave": compiled_text(
+            kt._insert_wave, tree, be, rows_b, rows_b, jnp.ones(b, bool),
+            levels, max_levels=mlev),
+        "_beam_search": compiled_text(
+            qm._beam_search, tree, qbe, rows_q, levels,
+            max_levels=mlev, beam=BEAM, k=K),
+        "_route_jit": compiled_text(
+            kt._route_jit, tree, be, rows_b, levels, max_levels=mlev),
+        "split_node": compiled_text(kt.split_node, tree, tree.root, key),
+        "split_nodes_batch": compiled_text(
+            kt.split_nodes_batch, tree, jnp.zeros(n_split, jnp.int32),
+            jnp.zeros(n_split, bool), jax.random.split(key, n_split)),
+    }
+    if rep == "rp":
+        texts["_chunk_candidates_jit"] = compiled_text(
+            qm._chunk_candidates_jit, tree, qbe, rows_q, levels,
+            max_levels=mlev, beam=BEAM)
+    check_kernels("build", kernel_calls(texts["_insert_wave"]),
+                  phase.build_kernels)
+    search = "_chunk_candidates_jit" if rep == "rp" else "_beam_search"
+    check_kernels("search", kernel_calls(texts[search]), phase.search_kernels)
+    check_tree_copies(tree, texts)
 
     if phase.store:
         run_store_queries(phase, tree, be, x_q, (docs, dist))

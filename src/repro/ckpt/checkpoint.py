@@ -138,18 +138,22 @@ def save_ktree(path: str, tree, projection=None) -> str:
 def restore_ktree(path: str):
     """Load a :func:`save_ktree` snapshot back into a live ``KTree`` (accepts
     the path with or without its ``.npz`` suffix; per-field dtypes restored
-    from the meta blob)."""
-    from repro.core.ktree import KTree
+    from the meta blob). A snapshot whose ``centers`` hold only the m+1
+    entry slots (written before the slot axis was padded) is padded to the
+    stored width on load."""
+    from repro.core.ktree import KTree, stored_slots
 
     data = np.load(path if path.endswith(".npz") else path + ".npz")
     meta = msgpack.unpackb(data["_meta"].tobytes())
     dtypes = meta.get("dtypes", {})  # absent in pre-fix checkpoints
-    kwargs = {
-        k: jnp.asarray(v, dtype=dtypes.get(k))
-        for k, v in data.items()
-        if k != "_meta"
-    }
-    return KTree(order=int(meta["order"]), medoid=bool(meta["medoid"]), **kwargs)
+    arrays = {k: v for k, v in data.items() if k != "_meta"}
+    order = int(meta["order"])
+    c = arrays["centers"]
+    pad = stored_slots(order + 1, dtypes.get("centers", c.dtype)) - c.shape[1]
+    if pad:
+        arrays["centers"] = np.pad(c, ((0, 0), (0, pad), (0, 0)))
+    kwargs = {k: jnp.asarray(v, dtype=dtypes.get(k)) for k, v in arrays.items()}
+    return KTree(order=order, medoid=bool(meta["medoid"]), **kwargs)
 
 
 def load_ktree_projection(path: str):

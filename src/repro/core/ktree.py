@@ -52,7 +52,8 @@ from repro.kernels.ref import EXACT
 @dataclasses.dataclass(frozen=True)
 class KTree:
     # --- data fields (device arrays) ---
-    centers: jax.Array       # f32[N, m+1, d] entry vectors/means (zeros invalid)
+    centers: jax.Array       # f32[N, S, d]   entry vectors/means (zeros invalid);
+                             #                S = stored_slots(m+1): slots ≥ m+1 are pad
     counts: jax.Array        # f32[N, m+1]    subtree weight per entry
     child: jax.Array         # i32[N, m+1]    doc id (leaf) / node id (internal)
     n_entries: jax.Array     # i32[N]
@@ -77,7 +78,36 @@ class KTree:
 
     @property
     def slots(self) -> int:  # order + 1
+        return self.order + 1
+
+    @property
+    def stored_slots(self) -> int:  # stored_slots(order + 1, dtype)
         return self.centers.shape[1]
+
+    def node_centers(self, node_ids) -> jax.Array:
+        """Entry vectors of ``node_ids`` → [..., m+1, d]: whole stored rows
+        gathered, then cut to the m+1 entry slots, so the pad is never read.
+        (A gather of ``centers[ids, :m+1]`` compiles for a TPU to a chain of
+        dynamic-update-slices into a buffer laid out ``{2,0,1}``.)"""
+        return self.centers[node_ids][..., : self.slots, :]
+
+
+def stored_slots(m1: int, dtype) -> int:
+    """Stored width of ``centers``' slot axis: ``m1`` rounded up to the
+    dtype's sublane tile (8 rows of 32-bit words, 16 of bf16). A TPU lays
+    out an array row-major only if that costs no more tile padding than
+    another order of its axes; at ``m1`` = 21 it would rather put the node
+    axis second-minor, and every program that gathers or scatters whole
+    ``[m1, d]`` nodes then copies the whole tree to row-major and back
+    (DESIGN.md §3). The pad slots stay zero and are never read.
+
+    The feature axis is not padded, so where ``d`` is not a multiple of
+    128 a layout with the node axis minor can still pad less than
+    row-major does, and the chip then picks it and the copies come back:
+    at d = 8000, trees of 8,672 or 9,032 nodes (24,000 or 25,000 docs at
+    order 20), 10,112 (28,000), or any past about 16,000 nodes."""
+    tile = max(8, 32 // jnp.dtype(dtype).itemsize)
+    return -(-m1 // tile) * tile
 
 
 def ktree_init(
@@ -85,7 +115,7 @@ def ktree_init(
 ) -> KTree:
     m1 = order + 1
     return KTree(
-        centers=jnp.zeros((max_nodes, m1, dim), dtype),
+        centers=jnp.zeros((max_nodes, stored_slots(m1, dtype), dim), dtype),
         counts=jnp.zeros((max_nodes, m1), dtype),
         child=jnp.full((max_nodes, m1), -1, jnp.int32),
         n_entries=jnp.zeros((max_nodes,), jnp.int32),
@@ -141,7 +171,7 @@ def _node_nearest_slot(
     Distances drop the ‖x‖² constant (same argmin). Per-query gathered node
     centres: the backend supplies the cross term — MXU einsum for dense rows,
     an nnz-bounded column gather for sparse rows."""
-    c = tree.centers[node_ids]                                   # [B, m1, d]
+    c = tree.node_centers(node_ids)                              # [B, m1, d]
     c_sq = jnp.einsum("bmd,bmd->bm", c, c, precision=EXACT)
     cross = backend.cross_nodes(rows, c)
     dist = c_sq - 2.0 * cross
@@ -156,7 +186,7 @@ def _root_nearest_slot(
     """Level-0 descent: every query is at the root, so its entries form one
     flat centre set — the fused flat-NN path (``nn_assign`` / ``ell_spmm``
     Pallas kernels on TPU, ref oracles elsewhere)."""
-    c = tree.centers[tree.root]                                  # [m1, d]
+    c = tree.node_centers(tree.root)                             # [m1, d]
     valid = jnp.arange(tree.slots) < tree.n_entries[tree.root]
     idx, _ = backend.nn_flat(rows, c, valid)
     return idx
@@ -221,7 +251,7 @@ def _nearest_in_leaf_backend(
 ):
     """(doc_id i32[B], sqdist f32[B]) — exact NN among the reached leaf's
     vectors, for any backend."""
-    c = tree.centers[leaf_ids]                                   # [B, m1, d]
+    c = tree.node_centers(leaf_ids)                              # [B, m1, d]
     c_sq = jnp.einsum("bmd,bmd->bm", c, c, precision=EXACT)
     diff_sq = c_sq - 2.0 * backend.cross_nodes(rows, c)
     valid = jnp.arange(tree.slots)[None, :] < tree.n_entries[leaf_ids][:, None]
@@ -272,7 +302,7 @@ def _insert_wave(
     nothing is pending (see :func:`build`).
 
     The input tree is donated — its buffers become the output's, so a wave
-    never holds two trees (4.9 GB each at INEX width and 20k docs) — and
+    never holds two trees (5.55 GB each at INEX width and 20k docs) — and
     must not be used after the call; so are the split ops'."""
     m1 = tree.slots
     nmax = tree.max_nodes
@@ -429,7 +459,7 @@ def split_node(tree: KTree, node_id: jax.Array, key: jax.Array) -> KTree:
     node_id = jnp.asarray(node_id, jnp.int32)
     parts = _split_parts(
         key,
-        tree.centers[node_id],
+        tree.node_centers(node_id),
         tree.counts[node_id],
         tree.child[node_id],
         tree.n_entries[node_id],
@@ -439,7 +469,8 @@ def split_node(tree: KTree, node_id: jax.Array, key: jax.Array) -> KTree:
     new_id = tree.n_nodes
     pos = jnp.arange(m1, dtype=jnp.int32)
 
-    centers = tree.centers.at[node_id].set(parts.left_centers).at[new_id].set(parts.right_centers)
+    centers = (tree.centers.at[node_id, :m1].set(parts.left_centers)
+               .at[new_id, :m1].set(parts.right_centers))
     counts = tree.counts.at[node_id].set(parts.left_counts).at[new_id].set(parts.right_counts)
     child = tree.child.at[node_id].set(parts.left_child).at[new_id].set(parts.right_child)
     n_entries = tree.n_entries.at[node_id].set(parts.n_left).at[new_id].set(parts.n_right)
@@ -519,7 +550,7 @@ def split_nodes_batch(
         functools.partial(_split_parts, medoid=tree.medoid)
     )(
         keys,
-        tree.centers[read],
+        tree.node_centers(read),
         tree.counts[read],
         tree.child[read],
         tree.n_entries[read],
@@ -530,7 +561,8 @@ def split_nodes_batch(
     node_safe = jnp.where(valid, node_ids, nmax)
     new_safe = jnp.where(valid, new_id, nmax)
 
-    centers = tree.centers.at[node_safe].set(parts.left_centers).at[new_safe].set(parts.right_centers)
+    centers = (tree.centers.at[node_safe, :m1].set(parts.left_centers)
+               .at[new_safe, :m1].set(parts.right_centers))
     counts = tree.counts.at[node_safe].set(parts.left_counts).at[new_safe].set(parts.right_counts)
     child = tree.child.at[node_safe].set(parts.left_child).at[new_safe].set(parts.right_child)
     n_entries = tree.n_entries.at[node_safe].set(parts.n_left).at[new_safe].set(parts.n_right)
@@ -986,7 +1018,8 @@ def check_invariants(tree: KTree, n_docs: Optional[int] = None, rtol: float = 1e
     5. dense mode: internal entry centre ≈ weighted mean of child entries,
     6. every allocated node is reachable from the root and slots past
        n_entries are cleared (child −1, zero weight),
-    7. every inserted doc appears in exactly one leaf slot, with in-range id.
+    7. every inserted doc appears in exactly one leaf slot, with in-range id,
+    8. the pad slots of ``centers`` (past m+1) hold zeros.
     Raises AssertionError on violation."""
     n = int(tree.n_nodes)
     ne = np.asarray(tree.n_entries[:n])
@@ -1038,6 +1071,7 @@ def check_invariants(tree: KTree, n_docs: Optional[int] = None, rtol: float = 1e
                     err = np.abs(centers[nd, s] - mean).max()
                     scale = max(np.abs(mean).max(), 1e-3)
                     assert err <= max(rtol * scale, 1e-3), f"mean mismatch {nd}:{s} err={err}"
+    assert not centers[:, tree.slots:].any(), "pad slots of centers written"
     leaf_heights = {height[nd] for nd in reachable if is_leaf[nd]}
     assert leaf_heights == {0}, f"unbalanced leaves: {leaf_heights}"
     if n_docs is not None:

@@ -108,7 +108,7 @@ def _score_entries(
     it out preserves bit-exact agreement with the greedy descent."""
     b, beam = frontier.shape
     m1 = tree.slots
-    c = tree.centers[frontier].reshape(b, beam * m1, tree.dim)
+    c = tree.node_centers(frontier).reshape(b, beam * m1, tree.dim)
     c_sq = jnp.einsum("bmd,bmd->bm", c, c, precision=EXACT)
     diff_sq = c_sq - 2.0 * backend.cross_nodes(rows, c)
     slot_ok = (
@@ -142,7 +142,7 @@ def _beam_frontier(
             # root fast path: one flat centre set → fused top-beam
             valid = jnp.arange(tree.slots) < tree.n_entries[tree.root]
             idx, _ = backend.topk_flat(
-                rows, tree.centers[tree.root], valid, beam
+                rows, tree.node_centers(tree.root), valid, beam
             )                                                # [B, beam]
             new_active = idx >= 0
             child_sel = tree.child[tree.root][jnp.maximum(idx, 0)]

@@ -9,6 +9,11 @@ d = 128 (Random Indexing projection), kq = beam (4) and k (10), and the ELL
 interpret mode accepts — misaligned blocks, too much VMEM, primitives Mosaic
 cannot lower — so these catch it without chip time.
 
+The last cases compile the five programs that take the whole tree
+(search, routing, the insertion wave, both splits) at the ``inex-dense``
+tree's shapes and count the copies of the stored tree in them: the chip's
+layout choice for ``centers`` (DESIGN.md §3) shows in the compiled text.
+
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library at a time, and
 every test worker imports this file.
@@ -88,3 +93,82 @@ def test_ell_spmm_compiles_for_v5e(one_chip, rows, nnz_max):
         ((ROOT_K, DENSE_D), jnp.float32),
     )
     _assert_kernel(compiled)
+
+
+# --- the programs that take the whole tree ---------------------------------
+# The inex-dense tree (20,000 docs of d = 8000 at order 20): 7,232 nodes.
+TREE_NODES, TREE_ORDER, TREE_DOCS = 7232, 20, 20_000
+
+
+def _chip_smoke():
+    """The repository's ``chip_smoke.py``, whose ``tree_copies`` the chip
+    run applies to the same compiled text."""
+    import importlib.util
+    import sys
+
+    if "chip_smoke" not in sys.modules:
+        path = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+        spec = importlib.util.spec_from_file_location("chip_smoke", path)
+        sys.modules["chip_smoke"] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules["chip_smoke"])
+    return sys.modules["chip_smoke"]
+
+
+def _tree_program(one_chip, program, stored=None):
+    """Compiled HLO text of ``program`` for the described chip, over the
+    inex-dense tree's shapes (``stored``: the centres' slot width, default
+    the tree's own), with the Pallas kernels the chip would run."""
+    import dataclasses
+
+    from repro.core import ktree as kt
+    from repro.core import query as qm
+    from repro.core.backend import DenseBackend
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tree = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: kt.ktree_init(TREE_NODES, TREE_ORDER, DENSE_D)),
+    )
+    if stored is not None:
+        tree = dataclasses.replace(
+            tree, centers=sds((TREE_NODES, stored, DENSE_D), jnp.float32))
+    be = DenseBackend(sds((TREE_DOCS, DENSE_D), jnp.float32))
+    rows, level = sds((BUILD_BATCH,), jnp.int32), sds((), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), 8))
+    calls = {
+        "_beam_search": lambda: qm._beam_search.lower(
+            tree, be, sds((1,), jnp.int32), level, max_levels=2, beam=BEAM, k=K),
+        "_insert_wave": lambda: kt._insert_wave.lower(
+            tree, be, rows, rows, sds((BUILD_BATCH,), bool), level, max_levels=2),
+        "_route_jit": lambda: kt._route_jit.lower(
+            tree, be, rows, level, max_levels=2),
+        "split_node": lambda: kt.split_node.lower(
+            tree, level, sds(key.shape, key.dtype)),
+        "split_nodes_batch": lambda: kt.split_nodes_batch.lower(
+            tree, sds((8,), jnp.int32), sds((8,), bool),
+            sds(keys.shape, keys.dtype)),
+    }
+    with jax.default_device(next(iter(one_chip.device_set))):
+        return calls[program]().compile().as_text(), tree.centers.shape
+
+
+@pytest.mark.parametrize("program", [
+    "_beam_search", "_insert_wave", "_route_jit", "split_node",
+    "split_nodes_batch",
+])
+def test_tree_programs_copy_no_tree(one_chip, program):
+    """Stored 24 slots wide, the tree stays row-major on the chip: no
+    program that takes it copies the whole of its centres."""
+    text, shape = _tree_program(one_chip, program)
+    assert shape == (TREE_NODES, 24, DENSE_D)
+    assert _chip_smoke().tree_copies(text, shape) == 0
+
+
+def test_tree_copy_seen_when_stored_unpadded(one_chip):
+    """The control: centres stored m+1 = 21 slots wide are laid out with the
+    node axis second-minor, and a search call copies the whole tree."""
+    text, shape = _tree_program(one_chip, "_beam_search", stored=TREE_ORDER + 1)
+    assert _chip_smoke().tree_copies(text, shape) >= 1

@@ -116,3 +116,46 @@ def test_restored_tree_accepts_insert_and_queries(tmp_path, medoid):
     grown2 = kt.insert(tree2, jnp.asarray(x[90:]), np.arange(90, 120), key=key)
     kt.check_invariants(grown2, n_docs=120)
     assert_trees_equal(grown, grown2)
+
+
+def test_padded_tree_roundtrip(tmp_path):
+    """Centres stored 24 slots wide for order 20 (the sublane tile pad,
+    DESIGN.md §3) come back 24 wide, pad slots zero, answers unchanged."""
+    rng = np.random.default_rng(8)
+    x = planted(rng, n=160)
+    tree = kt.build(x, order=20, batch_size=32)
+    assert tree.centers.shape[1] == 24 and int(tree.depth) >= 2
+    save_ktree(str(tmp_path / "t"), tree)
+    tree2 = restore_ktree(str(tmp_path / "t"))
+    assert_trees_equal(tree, tree2)
+    kt.check_invariants(tree2, n_docs=160)
+    np.testing.assert_array_equal(
+        topk_search(tree, x, k=5, beam=2)[0], topk_search(tree2, x, k=5, beam=2)[0]
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_unpadded_checkpoint_restores_padded(tmp_path, dtype):
+    """A snapshot written with m+1-wide centres (before the slot axis was
+    padded) restores to the stored width, padded with zeros on load."""
+    rng = np.random.default_rng(9)
+    x = planted(rng, n=160)
+    tree = kt.build(x, order=20, batch_size=32)
+    if dtype != jnp.float32:
+        tree = dataclasses.replace(
+            tree, centers=jnp.pad(tree.centers, ((0, 0), (0, 8), (0, 0)))
+            .astype(dtype))
+    width = kt.stored_slots(21, dtype)
+    assert tree.centers.shape[1] == width
+    path = save_ktree(str(tmp_path / "t"), tree)
+    with np.load(path) as data:
+        arrays = dict(data)
+    arrays["centers"] = arrays["centers"][:, :21]   # the old m+1-wide format
+    np.savez(path, **arrays)
+
+    tree2 = restore_ktree(path)
+    assert tree2.centers.shape == tree.centers.shape
+    assert_trees_equal(tree, tree2)
+    np.testing.assert_array_equal(
+        topk_search(tree, x, k=5, beam=2)[0], topk_search(tree2, x, k=5, beam=2)[0]
+    )
