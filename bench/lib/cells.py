@@ -3,11 +3,17 @@
 A traffic file's ``kind`` picks the driver: ``open_loop`` (top-k requests
 through ``ServingEngine`` at a fixed rate, shaped as ``lib/load.py`` reads
 the file) or ``builds`` (whole ``ktree.build`` runs of the corpus, back to
-back; ``corpus_seed`` fixes the corpus for every seed, and ``shuffle`` then
-lets the seed choose only the insertion order and the build's key).
-Everything a driver needs is in the configuration file and the traffic file;
-the program is reached only through ``make_backend``/``sparse_backend_from_csr``,
-``ktree.build``, ``make_search_fn`` and ``ServingEngine``.
+back; ``corpus_seed`` fixes the corpus for every seed, ``shuffle`` permutes
+its insertion order, and ``work_seed`` fixes that order and the build's key
+too, so that every seed builds the same tree and does the same work).
+Everything a driver needs is in the configuration file and the traffic file.
+The configuration's ``representation`` picks the route: ``dense`` and
+``sparse_medoid`` rows route as they are; ``rp`` (Random Indexing, DESIGN.md
+§5.1) routes ELL rows through the projection its ``rp`` block states, and
+search rescores the leaf pools from the ELL rows. The program is reached only
+through ``make_backend``/``sparse_backend_from_csr``, ``make_projection``,
+``RandomProjBackend.wrap`` (and its base's ``take``, to warm the rescore's
+gathers), ``ktree.build``, ``make_search_fn`` and ``ServingEngine``.
 """
 from __future__ import annotations
 
@@ -21,7 +27,7 @@ import time
 
 import numpy as np
 
-from lib import corpus as bcorpus, load, reference
+from lib import corpus as bcorpus, load, projection, reference
 from lib import trace as btrace
 
 COMPILE_EVENTS = (
@@ -80,8 +86,21 @@ def round_bf16(m: bcorpus.Csr) -> bcorpus.Csr:
         m, data=m.data.astype(ml_dtypes.bfloat16).astype(np.float32))
 
 
-def program_backend(cfg: dict, m: bcorpus.Csr):
-    """Hand the corpus rows to the program in the configuration's layout."""
+def is_rp(cfg: dict) -> bool:
+    """A Random Indexing configuration: the tree routes in a projection."""
+    return cfg["representation"] == "rp"
+
+
+def route_dim(cfg: dict, n_cols: int) -> int:
+    """Width of the space the tree routes in: the projection's, or the
+    rows' own ``n_cols``."""
+    return int(cfg["rp"]["dim"]) if is_rp(cfg) else n_cols
+
+
+def base_backend(cfg: dict, m: bcorpus.Csr):
+    """Hand the corpus rows to the program in the configuration's layout:
+    dense, or ELL at the configuration's ``nnz_max`` (``sparse_medoid`` and
+    the base rows of ``rp``)."""
     import jax.numpy as jnp
 
     from repro.core.backend import make_backend, sparse_backend_from_csr
@@ -96,6 +115,46 @@ def program_backend(cfg: dict, m: bcorpus.Csr):
     csr = Csr(jnp.asarray(m.data), jnp.asarray(m.indices),
               jnp.asarray(m.indptr), m.n_cols)
     return sparse_backend_from_csr(csr, nnz_max=cfg["nnz_max"])
+
+
+def routed(cfg: dict, base):
+    """The backend the tree is built on: ``base`` itself, or for ``rp`` the
+    base projected through the configuration's projection."""
+    if not is_rp(cfg):
+        return base
+    from repro.core.backend import RandomProjBackend, make_projection
+
+    rp = cfg["rp"]
+    return RandomProjBackend.wrap(base, make_projection(
+        base.dim, int(rp["dim"]), seed=int(rp["seed"]), kind=rp["kind"]))
+
+
+def program_backend(cfg: dict, m: bcorpus.Csr):
+    """The corpus rows as the backend the tree is built on."""
+    return routed(cfg, base_backend(cfg, m))
+
+
+def route(cfg: dict, docs: bcorpus.Csr):
+    """The reference's route space over ``docs``: None where the tree routes
+    in the rows' own space, else the float64 projection."""
+    if not is_rp(cfg):
+        return None
+    return reference.Projected(projection.matrix(docs.n_cols, cfg["rp"]), docs)
+
+
+def search_program(cfg: dict) -> str:
+    """The jitted program that descends the tree for one chunk of queries."""
+    return "_chunk_candidates_jit" if is_rp(cfg) else "_beam_search"
+
+
+def rescore_gather_sizes(calls, chunk_cap: int, order: int) -> list:
+    """Every row count of the RP rescore's gather (``_rp_row_fetcher``):
+    the program pads a chunk's candidate union, at most ``chunk rows × beam
+    × order`` documents, to a power of two, each of which compiles its own
+    gather."""
+    top = max(load.pow2((c or min(n, chunk_cap)) * beam * order)
+              for _, beam, n, c in calls)
+    return [1 << i for i in range(top.bit_length())]
 
 
 def build_tree(cfg: dict, be, key: int):
@@ -170,6 +229,7 @@ class ServeSetup:
     tree: object
     search_fn: object
     streams: Streams
+    cfg: dict
 
 
 def serve_setup(cfg: dict, traffic: dict, seed: int, *, control: bool = False,
@@ -194,10 +254,12 @@ def serve_setup(cfg: dict, traffic: dict, seed: int, *, control: bool = False,
     log(f"set-up: corpus of {n} docs and {n_pool} queries {time.perf_counter() - t:.1f} s")
     t = time.perf_counter()
     tree = build_tree(cfg, be, st.key)
+    # a Random Indexing search rescores from the base rows the backend holds
+    rp = be if is_rp(cfg) else None
     del be
     log(f"set-up: build {time.perf_counter() - t:.1f} s, depth {int(tree.depth)}, "
         f"{int(tree.n_nodes)} of {tree.max_nodes} nodes")
-    fn = make_search_fn(tree)
+    fn = make_search_fn(tree, rp=rp)
 
     def search_fn(x, k, beam, chunk_rows=None):
         with jax.profiler.TraceAnnotation("bench.search_fn"):
@@ -210,8 +272,13 @@ def serve_setup(cfg: dict, traffic: dict, seed: int, *, control: bool = False,
     for k, beam, rows, chunk_rows in calls:
         x = fed_pool[np.arange(rows) % len(fed_pool)]
         search_fn(x, k, beam, chunk_rows=chunk_rows)
+    if rp is not None:
+        import jax.numpy as jnp
+
+        for size in rescore_gather_sizes(calls, fn.chunk, cfg["order"]):
+            np.asarray(rp.base.take(jnp.asarray(np.zeros(size, np.int64), dtype=jnp.int32)))
     log(f"set-up: warm-up of {len(calls)} call shapes {time.perf_counter() - t:.1f} s")
-    return ServeSetup(docs, pool.dense(), fed_pool, tree, search_fn, st)
+    return ServeSetup(docs, pool.dense(), fed_pool, tree, search_fn, st, cfg)
 
 
 def window_plan(s: ServeSetup, traffic: dict, seconds: float, rate=None, *,
@@ -305,9 +372,10 @@ def check_served(s: ServeSetup, w: dict) -> dict:
     answered = np.nonzero(np.isfinite(w["t_done"]))[0]
     pick = np.sort(s.streams.sample.choice(
         answered, min(max(CHECK_SAMPLE // rows, 1), answered.size), replace=False))
-    host = reference.HostTree.from_device(s.tree)
+    host = reference.HostTree.from_device(s.tree, route_dim(s.cfg, s.docs.n_cols))
     s.tree = s.search_fn = None
-    ref = reference.RefTree(host, lambda ids: s.docs.dense(ids, np.float64))
+    ref = reference.RefTree(host, lambda ids: s.docs.dense(ids, np.float64),
+                            route(s.cfg, s.docs))
     gap, differ, n = 0.0, 0.0, 0
     for t in np.unique(plan.tenant[pick]):
         got = pick[plan.tenant[pick] == t]
@@ -364,13 +432,15 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float, trace_dir, *,
                         counter=counter, tracer=tracer)
         done = wt["t_done"]
         out["layer"]["rows_traced"] = rows * int(((done >= tracer.t0) & (done < tracer.t1)).sum())
+        program = search_program(cfg)
+        out["layer"]["search_program"] = program
         out["layer"]["trace"] = tracer.summary(("nn_topk", "nn_assign", "ell_spmm"),
-                                               ("_beam_search",))
+                                               (program,))
         shape = load.rows_per_call(traffic, s.search_fn.chunk)
         if shape is not None:
             out["layer"]["kernel_work"] = {"nn_topk": {
                 "rows": shape[0], "centres": cfg["order"] + 1,
-                "dim": cfg["corpus"]["culled_vocab"], "k": shape[1]}}
+                "dim": route_dim(cfg, s.docs.n_cols), "k": shape[1]}}
     gc.unfreeze()
     memory_peak(out)
 
@@ -408,25 +478,34 @@ def memory_peak(out: dict) -> None:
 # whole builds
 # ---------------------------------------------------------------------------
 
+def build_inputs(cfg: dict, traffic: dict, seed: int, n: int):
+    """The corpus of a ``builds`` cell, in its insertion order, and the
+    build's key. ``work_seed``, where the mix sets it, stands in for the seed
+    in every draw, so the seed no longer changes the tree or its work."""
+    st = Streams(traffic.get("work_seed", seed))
+    docs, _ = bcorpus.prepared_corpus(bcorpus.spec_from_config(cfg, n),
+                                      traffic.get("corpus_seed", st.seed))
+    if traffic.get("shuffle"):
+        docs = docs.take(st.order.permutation(n))
+    return docs, st.key
+
+
 def builds(cfg: dict, traffic: dict, seed: int, seconds: float, trace_dir, *,
            t_start: float, counter: CompileCounter, control: bool = False,
            n_docs: int | None = None) -> dict:
-    """One run of a ``builds`` cell; returns the raw readings."""
-    import jax
+    """One run of a ``builds`` cell; returns the raw readings. A Random
+    Indexing build projects its corpus inside each timed build: the
+    projection is part of what the RI K-tree's build costs."""
 
-    st = Streams(seed)
     n = n_docs or cfg["n_docs"]
     t = time.perf_counter()
-    docs, _ = bcorpus.prepared_corpus(bcorpus.spec_from_config(cfg, n),
-                                      traffic.get("corpus_seed", seed))
-    if traffic.get("shuffle"):
-        docs = docs.take(st.order.permutation(n))
-    be = program_backend(cfg, round_bf16(docs) if control else docs)
+    docs, key = build_inputs(cfg, traffic, seed, n)
+    base = base_backend(cfg, round_bf16(docs) if control else docs)
     log(f"set-up: corpus of {n} docs {time.perf_counter() - t:.1f} s")
     t = time.perf_counter()
     # the warm-up build runs the same corpus and key as the window's builds,
     # so it compiles every program (level and split-batch bucket) they use
-    tree = build_tree(cfg, be, st.key)
+    tree = build_tree(cfg, routed(cfg, base), key)
     del tree
     log(f"set-up: warm-up build {time.perf_counter() - t:.1f} s")
     tracer = Tracer(trace_dir) if trace_dir else None
@@ -440,7 +519,7 @@ def builds(cfg: dict, traffic: dict, seed: int, seconds: float, trace_dir, *,
             if tracer is not None and not times:
                 tracer.start()
             t = time.perf_counter()
-            tree = build_tree(cfg, be, st.key)
+            tree = build_tree(cfg, routed(cfg, base), key)
             times.append(time.perf_counter() - t)
             if tracer is not None and tracer.t1 is None:
                 tracer.stop()
@@ -467,16 +546,17 @@ def builds(cfg: dict, traffic: dict, seed: int, seconds: float, trace_dir, *,
         out["layer"]["trace"] = tracer.summary(
             ("nn_topk", "nn_assign", "ell_spmm"), ("_insert_wave", "split_node"))
         work = {"rows": cfg["batch_size"], "centres": cfg["order"] + 1,
-                "dim": cfg["corpus"]["culled_vocab"]}
+                "dim": route_dim(cfg, docs.n_cols)}
         if kernel == "ell_spmm":
             work["nnz"] = cfg["nnz_max"]
         out["layer"]["kernel_work"] = {kernel: work}
 
     t = time.perf_counter()
-    host = reference.HostTree.from_device(tree)
-    del tree, be
-    ref = reference.RefTree(host, lambda ids: docs.dense(ids, np.float64))
-    checks = {"misplaced_docs": ref.misplaced(n), "leaf_gap": ref.leaf_gap()}
+    host = reference.HostTree.from_device(tree, route_dim(cfg, docs.n_cols))
+    del tree, base
+    ref = reference.RefTree(host, lambda ids: docs.dense(ids, np.float64), route(cfg, docs))
+    checks = {"misplaced_docs": ref.misplaced(n)}
+    checks.update(ref.leaf_check())
     checks.update(ref.centre_checks())
     checks["compiles_in_window"] = compiles
     out["checks"] = checks

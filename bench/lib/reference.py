@@ -7,13 +7,18 @@ program computed: internal centres are recomputed here, as the mean of the
 documents under an entry (mean K-tree) or as the document an entry names
 (medoid K-tree, identified as the subtree document nearest to the entry).
 
-Build checks (``RefTree.misplaced``, ``leaf_gap``, ``centre_checks``) hold
-the program's tree to the K-tree's guarantees: every document in exactly
-one leaf, all leaves at one depth, each leaf entry the document's own row,
-each internal entry the mean (and count) of its subtree, or one of its
-subtree's documents. Serve checks
-(``check_answers``) hold served answers to the float64 distances of the rows
-they name, and to the reference's own beam search over the same tree.
+The route and the score are apart. The tree routes in a space of its own
+(``Identity``: the rows themselves, for dense and ELL configurations;
+``Projected``: ``x·P`` in float64, for a Random Indexing one), and answers
+are scored on the original rows. Build checks (``RefTree.misplaced``,
+``leaf_check``, ``centre_checks``) hold the program's tree to the K-tree's
+guarantees in the route space: every document in exactly one leaf, all
+leaves at one depth, each leaf entry the document's own route row, each
+internal entry the mean (and count) of its subtree, or one of its subtree's
+documents. Serve checks (``check_answers``) hold served answers to the
+float64 distances of the original rows they name, and to the reference's own
+beam search over the same tree: a descent in the route space, then the exact
+original-space distances of the documents in the final beam's leaves.
 """
 from __future__ import annotations
 
@@ -24,6 +29,42 @@ import numpy as np
 TIE_RTOL = 1e-5
 """Two distances closer than this share of ‖q‖² + ‖x‖² tie: the reference
 takes either order (f32 rounding of a squared distance is ~1e-7 of it)."""
+
+
+class Identity:
+    """The route space of a tree built on the rows themselves."""
+    leaf_name = "leaf_gap"  # a leaf entry is a copy of its row: exact
+
+    def __init__(self, rows64):
+        self.rows = rows64
+
+    @staticmethod
+    def of(x64: np.ndarray) -> np.ndarray:
+        return x64
+
+
+class Projected:
+    """The route space of a Random Indexing tree: ``x ↦ x·P`` in float64,
+    with the route rows of the whole corpus computed once from its sparse
+    rows (never densified whole)."""
+    leaf_name = "route_gap"  # the program projects in float32: not a copy
+
+    def __init__(self, p32: np.ndarray, csr):
+        """``p32``: P, ``f32[n_cols, dim]``; ``csr``: the corpus rows
+        (``data``, ``indices``, ``indptr``)."""
+        import scipy.sparse
+
+        self.p64 = p32.astype(np.float64)
+        m = scipy.sparse.csr_matrix(
+            (csr.data.astype(np.float64), csr.indices, csr.indptr),
+            shape=(csr.indptr.size - 1, self.p64.shape[0]))
+        self.z64 = np.asarray(m @ self.p64)
+
+    def rows(self, ids) -> np.ndarray:
+        return self.z64[np.asarray(ids, np.int64)]
+
+    def of(self, x64: np.ndarray) -> np.ndarray:
+        return x64 @ self.p64
 
 
 @dataclasses.dataclass
@@ -40,9 +81,11 @@ class HostTree:
     medoid: bool
 
     @classmethod
-    def from_device(cls, tree) -> "HostTree":
+    def from_device(cls, tree, dim: int) -> "HostTree":
+        """The tree's arrays on the host; ``centers`` cut to the ``dim``
+        features of the route space, whatever the program pads it to."""
         return cls(
-            centers=np.asarray(tree.centers), counts=np.asarray(tree.counts),
+            centers=np.asarray(tree.centers)[:, :, :dim], counts=np.asarray(tree.counts),
             child=np.asarray(tree.child), n_entries=np.asarray(tree.n_entries),
             is_leaf=np.asarray(tree.is_leaf), root=int(tree.root),
             n_nodes=int(tree.n_nodes), depth=int(tree.depth),
@@ -52,12 +95,15 @@ class HostTree:
 
 class RefTree:
     """The reference's view of a built tree: its walk from the root, each
-    node's documents, and float64 centres of every internal entry."""
+    node's documents, and float64 centres of every internal entry, in the
+    route space."""
 
-    def __init__(self, t: HostTree, rows64):
-        """``rows64(ids) -> f64[len(ids), d]``: the benchmark's corpus rows."""
+    def __init__(self, t: HostTree, rows64, route=None):
+        """``rows64(ids) -> f64[len(ids), d]``: the benchmark's corpus rows;
+        ``route``: the space the tree routes in (default ``Identity``)."""
         self.t = t
         self.rows64 = rows64
+        self.route = route or Identity(rows64)
         self.faults = 0           # structural faults found on the walk
         self.node_docs: dict[int, np.ndarray] = {}
         self.leaf_of: dict[int, int] = {}
@@ -108,9 +154,14 @@ class RefTree:
         missing = n_docs - int(((ids >= 0) & (ids < n_docs)).sum())
         return missing + bad_ids + self.dup_docs + self.faults
 
+    def leaf_check(self) -> dict:
+        """``{route.leaf_name: leaf_gap()}``: ``leaf_gap`` where a leaf entry
+        copies its row, ``route_gap`` where the program computed it."""
+        return {self.route.leaf_name: self.leaf_gap()}
+
     def leaf_gap(self) -> float:
-        """Largest |leaf entry − the row of the document it names|, as a share
-        of that row's largest element. A copy is exact: 0."""
+        """Largest |leaf entry − the route row of the document it names|, as
+        a share of that row's largest element. A copy is exact: 0."""
         worst = 0.0
         for node in self.order:
             if not self.t.is_leaf[node]:
@@ -119,7 +170,7 @@ class RefTree:
             ok = (docs >= 0)
             if not ok.any():
                 continue
-            x = self.rows64(docs[ok])
+            x = self.route.rows(docs[ok])
             c = self.t.centers[node, : docs.size][ok].astype(np.float64)
             scale = np.abs(x).max(axis=1)
             worst = max(worst, float((np.abs(c - x).max(axis=1) / scale).max()))
@@ -146,7 +197,7 @@ class RefTree:
                 c = self.t.centers[node, s].astype(np.float64)
                 best, best_row = np.inf, None
                 for lo in range(0, docs.size, 2048):
-                    x = self.rows64(docs[lo: lo + 2048])
+                    x = self.route.rows(docs[lo: lo + 2048])
                     gap = np.abs(x - c).max(axis=1) / np.maximum(np.abs(x).max(axis=1), 1e-300)
                     i = int(gap.argmin())
                     if gap[i] < best:
@@ -158,7 +209,7 @@ class RefTree:
         for node in reversed(self.order):
             if self.t.is_leaf[node]:
                 docs = self._entries(node)
-                sums[node] = self.rows64(docs[docs >= 0]).sum(axis=0)
+                sums[node] = self.route.rows(docs[docs >= 0]).sum(axis=0)
             else:
                 sums[node] = sum(sums.get(int(c), 0.0) for c in self._entries(node))
         mean_gap, count_gap = 0.0, 0.0
@@ -171,16 +222,18 @@ class RefTree:
         return {"mean_gap": mean_gap, "count_gap": count_gap}
 
     def beam_search(self, q64: np.ndarray, k: int, beam: int):
-        """Reference top-k: beam descent over the reference centres, then the
-        exact float64 distances of the documents in the final beam's leaves.
-        Returns (doc ids [k], distances [k]) ascending."""
+        """Reference top-k: beam descent over the reference centres in the
+        route space, then the exact float64 distances of the original rows of
+        the documents in the final beam's leaves (brute force restricted to
+        that pool). Returns (doc ids [k], distances [k]) ascending."""
         t = self.t
+        qr = self.route.of(q64)
         frontier = [t.root]
         for _ in range(t.depth - 1):
             cands = []
             for node in frontier:
                 for s, c in enumerate(self._entries(node)):
-                    d = float(((self.ref_centres[(node, s)] - q64) ** 2).sum())
+                    d = float(((self.ref_centres[(node, s)] - qr) ** 2).sum())
                     cands.append((d, int(c)))
             cands.sort(key=lambda dc: dc[0])
             frontier = [c for _, c in cands[:beam]]

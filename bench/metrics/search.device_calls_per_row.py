@@ -1,5 +1,7 @@
 """Executions of the jitted search step per query row answered, in the traced
-window."""
+window: the program the driver names in ``layer["search_program"]`` (the
+route's descent: ``_beam_search``, or ``_chunk_candidates_jit`` for a Random
+Indexing tree), ``_beam_search`` where it names none."""
 
 
 def read(layer):
@@ -7,4 +9,4 @@ def read(layer):
     rows = layer.get("rows_traced", 0)
     if tr is None or not rows:
         return None
-    return tr["program_calls"]["_beam_search"] / rows
+    return tr["program_calls"][layer.get("search_program", "_beam_search")] / rows

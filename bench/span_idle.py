@@ -53,23 +53,19 @@ def build_window(spec: dict, seed: int, out_dir: str, n_docs=None):
     traced build that holds a profiler; the profiler and the batches run."""
     import jax
 
-    from lib import cells, corpus as bcorpus
+    from lib import cells
     from repro.core import ktree
     from repro.core.profile import Profiler
 
     cfg, traffic = spec["config"], spec["traffic"]
-    st = cells.Streams(seed)
     n = n_docs or cfg["n_docs"]
-    docs, _ = bcorpus.prepared_corpus(bcorpus.spec_from_config(cfg, n),
-                                      traffic.get("corpus_seed", seed))
-    if traffic.get("shuffle"):
-        docs = docs.take(st.order.permutation(n))
+    docs, key = cells.build_inputs(cfg, traffic, seed, n)
     be = cells.program_backend(cfg, docs)
-    cells.build_tree(cfg, be, st.key)  # compiles every program the build runs
+    cells.build_tree(cfg, be, key)  # compiles every program the build runs
     prof = Profiler()
     tracer = cells.Tracer(out_dir)
     tracer.start()
-    tree = ktree.build(be, order=cfg["order"], key=jax.random.PRNGKey(st.key),
+    tree = ktree.build(be, order=cfg["order"], key=jax.random.PRNGKey(key),
                        batch_size=cfg["batch_size"], medoid=cfg["medoid"], profiler=prof)
     jax.block_until_ready(tree)
     tracer.stop()

@@ -72,3 +72,22 @@ def test_engine_calls_follow_rows_cache_and_tenants():
     ts = [{"k": 10, "beam": 4, "share": 1}, {"k": 5, "beam": 2, "share": 1}]
     two = load.engine_calls(dict(ONE_ROW, tenants=ts), 512)
     assert len(two) == 16 and load.rows_per_call(dict(ONE_ROW, tenants=ts), 512) is None
+
+
+@pytest.mark.parametrize("mix,config", [("whole_builds", "inex-dense"),
+                                        ("whole_builds_shuffled", "rcv1-ell")])
+def test_build_mixes_do_the_same_work_for_every_seed(mix, config):
+    import os
+
+    import run
+    from lib import cells
+
+    cfg = run.load_json(os.path.join(run.BENCH, "configs", config + ".json"))
+    traffic = run.load_json(os.path.join(run.BENCH, "traffic", mix + ".json"))
+    (a, ka), (b, kb) = (cells.build_inputs(cfg, traffic, s, 300) for s in (3, 2**31 + 9))
+    assert ka == kb and a.n_cols == b.n_cols
+    assert np.array_equal(a.dense(np.arange(300)), b.dense(np.arange(300)))
+    # without work_seed the seed draws the order and the key, as before
+    free = {k: v for k, v in traffic.items() if k != "work_seed"}
+    (_, kc), (_, kd) = (cells.build_inputs(cfg, free, s, 300) for s in (3, 2**31 + 9))
+    assert kc != kd
